@@ -46,7 +46,7 @@ from typing import List, Tuple
 
 import numpy as np
 
-from benchmarks.common import (PAPER_TPUT, Row, StageTimer, job_stream_arrays,
+from benchmarks.common import (PAPER_TPUT, Row, job_stream_arrays,
                                merge_bench_rows, paper_market, timed)
 from benchmarks.pool_sim_bench import _JSON_PATH
 
@@ -127,13 +127,11 @@ def run() -> List[Row]:
             arrs, jobs, PAPER_TPUT, pw, aw, preds, mesh=mesh,
             collect=collect, fallback=fallback)
 
-    st = StageTimer()
     rows: List[Row] = []
     ledgers = {}
     gains: List[Tuple[int, float]] = []
     for n_storms in INTENSITY:
-        with st.stage(f"prep_s{n_storms}"):
-            inputs = build_inputs(n_storms)
+        inputs = build_inputs(n_storms)
         select(inputs, None)                      # warm-up pays compilation
         res_off, us_off = timed(select, inputs, None, repeat=max(REPEAT, 1))
         u_off = float(res_off.mean_utility[ahap].mean())
@@ -148,8 +146,7 @@ def run() -> List[Row]:
                 gains.append((n_storms, u_on - u_off))
                 # flight-recorder pass OUTSIDE the timed runs, pinned
                 # bitwise to the timed on-run (collect only ADDS outputs)
-                with st.stage(f"telemetry_s{n_storms}"):
-                    res_c = select(inputs, cfg, collect=True)
+                res_c = select(inputs, cfg, collect=True)
                 np.testing.assert_array_equal(res_c.mean_utility,
                                               res_on.mean_utility)
                 led = pool_ledger(res_c.sim_out, inputs[0], PAPER_TPUT)
@@ -172,7 +169,6 @@ def run() -> List[Row]:
     worst = max(INTENSITY)
     gain = dict(gains)[worst]
     rows.append((f"chaos_gain__s{worst}", 0.0, gain))
-    rows += st.rows("chaos")
 
     extra = {
         "workload": {

@@ -3,7 +3,6 @@
 computation, derived is the figure's headline number."""
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, List, Tuple
@@ -56,32 +55,6 @@ def timed(fn: Callable, *args, repeat: int = 1, block: bool = True, **kw):
             _block(out)
     dt = (time.perf_counter() - t0) / repeat
     return out, dt * 1e6  # us
-
-
-class StageTimer:
-    """Accumulating named stage clock for a benchmark's prep/simulate/select
-    split. ``with st.stage("simulate"): ...`` adds that block's wall time
-    (blocking on ``block_on`` if given); ``rows(prefix)`` emits standard
-    bench rows (derived = share of total)."""
-
-    def __init__(self):
-        self.totals: dict = {}
-
-    @contextlib.contextmanager
-    def stage(self, name: str, block_on=None):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            if block_on is not None:
-                _block(block_on() if callable(block_on) else block_on)
-            self.totals[name] = (self.totals.get(name, 0.0)
-                                 + time.perf_counter() - t0)
-
-    def rows(self, prefix: str) -> List[Row]:
-        total = sum(self.totals.values()) or 1.0
-        return [(f"{prefix}_stage_{name}", dt * 1e6, dt / total)
-                for name, dt in self.totals.items()]
 
 
 def job_stream_arrays(rng: np.random.Generator, n: int, deadline: int = 10,

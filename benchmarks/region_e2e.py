@@ -27,9 +27,8 @@ deterministic 1.0, not a statistical one. The headline
 ``region_e2e_engine_vs_loop`` row is loop-seconds over engine-seconds
 (>= 1.0 means the engine pays for itself); the opt-in regression guard
 (tests/test_bench_regression.py, RUN_BENCH_REGRESSION=1) pins both at
-the 1000-job scale. The prep / simulate / select split is recorded via
-StageTimer, plus ``region_e2e_prep_numpy`` vs ``region_e2e_prep_jax``
-rows comparing the host-numpy forecast stack against the jitted
+the 1000-job scale. ``region_e2e_prep_numpy`` vs ``region_e2e_prep_jax``
+rows compare the host-numpy forecast stack against the jitted
 batched-PRNG device path (``prep_backend="jax"``). Rows are folded into
 BENCH_pool_sim.json (region_e2e rows replaced in place, the rest
 untouched).
@@ -50,7 +49,6 @@ import numpy as np
 
 from benchmarks.common import (
     PAPER_TPUT,
-    StageTimer,
     job_stream_arrays,
     merge_bench_rows,
     numpy_eg_loop,
@@ -157,22 +155,6 @@ def run():
         prep=lambda lo, hi: prep("numpy", lo, hi),
     )
 
-    # --- stage split: one full engine pass, prep/simulate/select timed ---
-    # separately (NOT double-buffered — the split shows what overlap hides;
-    # the total row below is the double-buffered streamed engine)
-    st = StageTimer()
-    with st.stage("prep"):
-        prices, avail, preds = prep("numpy")
-    sim = lambda: fast_sim.simulate_pool_regions_sharded(
-        arrs, jobs, PAPER_TPUT, prices, avail, preds,
-        delta_mig=DELTA_MIG, mesh=mesh,
-    )
-    with st.stage("simulate", block_on=lambda: sim()["utility"]):
-        u_dev = sim()["utility"]
-    with st.stage("select", block_on=lambda: engine.select_from_utilities(
-            jobs, u_dev, selector.eg_init(n_pol, N_JOBS))[0].weights):
-        pass
-
     res, total_secs = _timeit(engine_run)
     _, prep_np_secs = _timeit(lambda: prep("numpy"))
     _, prep_jax_secs = _timeit(
@@ -185,8 +167,7 @@ def run():
         repeat=1,
     )
 
-    rows = st.rows("region_e2e")
-    rows += [
+    rows = [
         ("region_e2e_total", total_secs * 1e6, units / total_secs),
         ("region_e2e_loop", loop_secs * 1e6, units / loop_secs),
         ("region_e2e_prep_numpy", prep_np_secs * 1e6, units / prep_np_secs),

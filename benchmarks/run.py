@@ -2,7 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV. Run:
     PYTHONPATH=src python -m benchmarks.run [--only fig5,fig9] \
-        [--json out.json] [--profile-dir traces/]
+        [--json out.json]
 
 ``--json <path>`` additionally captures every module's rows as a
 machine-readable payload ``{schema_version, backend, devices, elapsed_s,
@@ -12,10 +12,6 @@ opt-in CI regression guard (tests/test_bench_regression.py reads the
 pool_sim speedup rows from it). ``provenance`` pins what produced the
 numbers: git sha, jax/python versions, platform, device count, UTC
 timestamp.
-
-``--profile-dir <dir>`` wraps the whole module loop in a
-``jax.profiler.trace`` capture (viewable in TensorBoard / Perfetto) —
-opt-in because tracing adds overhead and trace files are large.
 """
 from __future__ import annotations
 
@@ -100,8 +96,6 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma-separated module prefixes")
     ap.add_argument("--json", default="",
                     help="also write all rows to this path as JSON")
-    ap.add_argument("--profile-dir", default="",
-                    help="capture a jax.profiler trace of the run here")
     args = ap.parse_args()
     selected, unknown = select_modules(args.only)
     if unknown:
@@ -109,14 +103,6 @@ def main() -> None:
             f"unknown benchmark name(s): {', '.join(unknown)}\n"
             f"known modules: {', '.join(MODULES)}"
         )
-
-    profile_ctx = None
-    if args.profile_dir:
-        import jax
-
-        profile_ctx = jax.profiler.trace(args.profile_dir)
-        profile_ctx.__enter__()
-        print(f"# profiling to {args.profile_dir}", flush=True)
 
     print("name,us_per_call,derived")
     failures = 0
@@ -143,8 +129,6 @@ def main() -> None:
             })
             traceback.print_exc(file=sys.stderr)
         print(f"# {mod_name} done in {time.time()-t0:.1f}s", flush=True)
-    if profile_ctx is not None:
-        profile_ctx.__exit__(None, None, None)
     if args.json:
         import jax  # benchmark modules have long since initialized it
 

@@ -60,8 +60,7 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
-from benchmarks.common import (PAPER_TPUT, StageTimer, job_stream_arrays,
-                               merge_bench_rows)
+from benchmarks.common import PAPER_TPUT, job_stream_arrays, merge_bench_rows
 from benchmarks.pool_sim_bench import _JSON_PATH
 
 
@@ -302,22 +301,17 @@ def run():
     mesh = make_pool_mesh(
         shape=parse_pool_mesh_shape(os.environ.get("POOL_SIM_MESH", ""))
     )
-    st = StageTimer()
-    with st.stage("prep"):
-        jobs, prices, avail, preds, _ = build_grid_inputs(regimes)
+    jobs, prices, avail, preds, _ = build_grid_inputs(regimes)
 
     ev = lambda: evaluate_grid(arrs, regimes, jobs, prices, avail, preds,
                                mesh=mesh)
-    with st.stage("compile"):
-        util = ev()                 # warm-up call pays compilation
+    util = ev()                     # warm-up call pays compilation
     t0 = time.perf_counter()
-    with st.stage("simulate"):
-        for _ in range(max(REPEAT, 1)):
-            ev()
+    for _ in range(max(REPEAT, 1)):
+        ev()
     secs = (time.perf_counter() - t0) / max(REPEAT, 1)
 
-    with st.stage("analyze"):
-        res = analyze_grid(pool, regimes, util, jobs)
+    res = analyze_grid(pool, regimes, util, jobs)
     eg_ratios = [p["eg_regret_ratio"] for p in res["per_regime"]]
     units = len(regimes) * util.shape[1] * len(pool) * DEADLINE
     rows = [
@@ -347,22 +341,21 @@ def run():
         # flight-recorder pass OUTSIDE the timed sweep: collect=False above
         # keeps the timings on the exact shipped program, and the bitwise
         # self-check below proves the collect path didn't perturb it
-        with st.stage("telemetry"):
-            util_t, sim_out = evaluate_grid(
-                arrs, regimes, jobs, prices, avail, preds, mesh=mesh,
-                collect=True,
-            )
-            tputs = [ThroughputConfig(alpha=PAPER_TPUT.alpha,
-                                      beta=PAPER_TPUT.beta,
-                                      mu1=r.mu1, mu2=r.mu2)
-                     for r in regimes]
-            meta = [{"key": r.key, "avail_mean": r.avail_mean,
-                     "price_sigma": r.price_sigma, "tight": r.tight,
-                     "mu1": r.mu1, "mu2": r.mu2, "noise": r.noise}
-                    for r in regimes]
-            telemetry = grid_ledger(meta, util_t, sim_out, jobs, tputs,
-                                    util.shape[1],
-                                    lane_names=[p.name for p in pool])
+        util_t, sim_out = evaluate_grid(
+            arrs, regimes, jobs, prices, avail, preds, mesh=mesh,
+            collect=True,
+        )
+        tputs = [ThroughputConfig(alpha=PAPER_TPUT.alpha,
+                                  beta=PAPER_TPUT.beta,
+                                  mu1=r.mu1, mu2=r.mu2)
+                 for r in regimes]
+        meta = [{"key": r.key, "avail_mean": r.avail_mean,
+                 "price_sigma": r.price_sigma, "tight": r.tight,
+                 "mu1": r.mu1, "mu2": r.mu2, "noise": r.noise}
+                for r in regimes]
+        telemetry = grid_ledger(meta, util_t, sim_out, jobs, tputs,
+                                util.shape[1],
+                                lane_names=[p.name for p in pool])
         bitwise = bool(np.array_equal(util, util_t))
         rows += [
             ("scenario_grid_tel_bitwise_match", 0.0, float(bitwise)),
@@ -374,8 +367,6 @@ def run():
         os.makedirs(os.path.dirname(TELEMETRY_JSON) or ".", exist_ok=True)
         with open(TELEMETRY_JSON, "w") as f:
             json.dump(telemetry, f, indent=2)
-
-    rows += st.rows("scenario_grid")
 
     extra = {
         "workload": {

@@ -27,6 +27,13 @@ tests/test_selection_engine.py pins both).
 Benchmarks: benchmarks/selection_e2e.py records the prep/simulate/select
 split and pins the engine against the pre-engine host-loop pipeline
 (``SEL_E2E_JOBS`` knob, rows in BENCH_pool_sim.json).
+
+Tracing: each layer boundary is a ``jax.profiler.TraceAnnotation`` named
+``sched.*`` (``sched.prep`` with ``.windows`` and ``.forecast``;
+``sched.select`` around ``sched.stage``, the pool's ``sched.pool.*``,
+``sched.eg`` and ``sched.fetch``), recorded only while a profiler capture
+runs; ``repro.obs.counters`` counts the call's host-device transfers and
+device launches.
 """
 from __future__ import annotations
 
@@ -37,6 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ThroughputConfig
 from repro.core import fast_sim, selector
@@ -47,6 +55,7 @@ from repro.core.predictor import (
     noisy_matrix_batch_jax,
     regional_noisy_matrix_jax,
 )
+from repro.obs import counters
 
 
 def prepare_noisy_inputs(trace, t0s, deadline: int, kind: str, level,
@@ -69,18 +78,22 @@ def prepare_noisy_inputs(trace, t0s, deadline: int, kind: str, level,
     numpy oracle (winner/regret parity pinned in
     tests/test_region_engine.py)."""
     horizon = fast_sim.W1MAX - 1 if horizon is None else horizon
-    pw, aw = gather_windows(trace, t0s, deadline + 1)
-    if prep_backend == "jax":
-        preds = noisy_matrix_batch_jax(pw, aw, kind, level, seeds, horizon,
-                                       avail_max)[:, :deadline]
-    else:
-        preds = noisy_matrix_batch(pw, aw, kind, level, seeds, horizon,
-                                   avail_max)[:, :deadline]
-        require_finite("forecast stack", preds)
-        preds = preds.astype(np.float32)
-    return (pw[:, :deadline].astype(np.float32),
-            aw[:, :deadline].astype(np.int64),
-            preds)
+    with TraceAnnotation("sched.prep", jobs=int(np.size(t0s))):
+        with TraceAnnotation("sched.prep.windows"):
+            pw, aw = gather_windows(trace, t0s, deadline + 1)
+        with TraceAnnotation("sched.prep.forecast"):
+            if prep_backend == "jax":
+                preds = noisy_matrix_batch_jax(pw, aw, kind, level, seeds,
+                                               horizon,
+                                               avail_max)[:, :deadline]
+            else:
+                preds = noisy_matrix_batch(pw, aw, kind, level, seeds,
+                                           horizon, avail_max)[:, :deadline]
+                require_finite("forecast stack", preds)
+                preds = preds.astype(np.float32)
+            return (pw[:, :deadline].astype(np.float32),
+                    aw[:, :deadline].astype(np.int64),
+                    preds)
 
 
 def prepare_noisy_inputs_regions(market, t0s, deadline: int, kind: str,
@@ -107,31 +120,37 @@ def prepare_noisy_inputs_regions(market, t0s, deadline: int, kind: str,
     pinned, as for the single-region path)."""
     horizon = fast_sim.W1MAX - 1 if horizon is None else horizon
     n_regions = market.n_regions
-    pws, aws = zip(*(gather_windows(market.region(r), t0s, deadline + 1)
-                     for r in range(n_regions)))
-    pw = np.stack(pws, axis=1)                    # (K, R, d+1)
-    aw = np.stack(aws, axis=1)
-    n_jobs = pw.shape[0]
-    seeds = np.asarray(seeds)
-    rseeds = seeds[:, None] * np.int64(1009) + np.arange(n_regions)[None, :]
-    if prep_backend == "jax":
-        preds = regional_noisy_matrix_jax(
-            pw, aw, kind, level, rseeds, horizon, avail_max
-        )[:, :, :deadline]
-    else:
-        level_arr = np.asarray(level, float)
-        lv = np.repeat(level_arr, n_regions) if level_arr.ndim else level_arr
-        preds = noisy_matrix_batch(
-            pw.reshape(n_jobs * n_regions, -1),
-            aw.reshape(n_jobs * n_regions, -1),
-            kind, lv, rseeds.reshape(-1), horizon, avail_max,
-        ).reshape(n_jobs, n_regions, deadline + 1, horizon + 1, 2)
-        preds = preds[:, :, :deadline]
-        require_finite("forecast stack", preds)
-        preds = preds.astype(np.float32)
-    return (pw[:, :, :deadline].astype(np.float32),
-            aw[:, :, :deadline].astype(np.int64),
-            preds)
+    with TraceAnnotation("sched.prep", jobs=int(np.size(t0s))):
+        with TraceAnnotation("sched.prep.windows"):
+            pws, aws = zip(*(gather_windows(market.region(r), t0s,
+                                            deadline + 1)
+                             for r in range(n_regions)))
+            pw = np.stack(pws, axis=1)                    # (K, R, d+1)
+            aw = np.stack(aws, axis=1)
+        with TraceAnnotation("sched.prep.forecast"):
+            n_jobs = pw.shape[0]
+            seeds = np.asarray(seeds)
+            rseeds = (seeds[:, None] * np.int64(1009)
+                      + np.arange(n_regions)[None, :])
+            if prep_backend == "jax":
+                preds = regional_noisy_matrix_jax(
+                    pw, aw, kind, level, rseeds, horizon, avail_max
+                )[:, :, :deadline]
+            else:
+                level_arr = np.asarray(level, float)
+                lv = (np.repeat(level_arr, n_regions) if level_arr.ndim
+                      else level_arr)
+                preds = noisy_matrix_batch(
+                    pw.reshape(n_jobs * n_regions, -1),
+                    aw.reshape(n_jobs * n_regions, -1),
+                    kind, lv, rseeds.reshape(-1), horizon, avail_max,
+                ).reshape(n_jobs, n_regions, deadline + 1, horizon + 1, 2)
+                preds = preds[:, :, :deadline]
+                require_finite("forecast stack", preds)
+                preds = preds.astype(np.float32)
+            return (pw[:, :, :deadline].astype(np.float32),
+                    aw[:, :, :deadline].astype(np.int64),
+                    preds)
 
 
 @functools.partial(jax.jit, static_argnames=("track_history", "collect"))
@@ -267,9 +286,9 @@ def simulate_and_select(
     ``None``. The chunk loop DOUBLE-BUFFERS: chunk k's simulate/select
     work is dispatched asynchronously, then chunk k+1's prep runs on the
     host while the device chews — the prep leg hides behind the simulate
-    leg instead of serializing with it (benchmarks/region_e2e.py measures
-    the split via StageTimer). ``prep=None`` slices the passed arrays,
-    which is the same values in the same order — results are unchanged."""
+    leg instead of serializing with it. ``prep=None`` slices the passed
+    arrays, which is the same values in the same order — results are
+    unchanged."""
     n_jobs = int(np.shape(jobs.workload)[0])
     n_pol = int(np.asarray(pool_arrays["kind"]).shape[0])
     if state is None:
@@ -282,80 +301,101 @@ def simulate_and_select(
         raise ValueError("pass (prices, avail, preds) arrays or prep=")
 
     def _stage(lo, hi):
-        if prep is not None:
-            p, a, m = prep(lo, hi)
-        else:
-            p, a, m = prices[lo:hi], avail[lo:hi], preds[lo:hi]
-        # jnp.asarray starts the host->device transfer right away, so a
-        # staged chunk is already in flight when its sim dispatches
-        return jnp.asarray(p), jnp.asarray(a), jnp.asarray(m)
+        with TraceAnnotation("sched.stage", lo=lo, hi=hi):
+            if prep is not None:
+                p, a, m = prep(lo, hi)
+            else:
+                p, a, m = prices[lo:hi], avail[lo:hi], preds[lo:hi]
+                # slicing a device array short of its length runs a program
+                counters.add("launches", sum(
+                    isinstance(x, jax.Array) and hi - lo < x.shape[0]
+                    for x in (prices, avail, preds)))
+            # jnp.asarray starts the host->device transfer right away, so a
+            # staged chunk is already in flight when its sim dispatches
+            counters.h2d((p, a, m))
+            return jnp.asarray(p), jnp.asarray(a), jnp.asarray(m)
 
-    u_sum = jnp.zeros((n_pol,), jnp.float32)
-    max_w, regrets, hist, raw = [], [], [], []
-    ent, top, sim_chunks = [], [], []
-    spans = [(lo, min(lo + chunk, n_jobs))
-             for lo in range(0, n_jobs, chunk)]
-    staged = _stage(*spans[0])
-    for i, (lo, hi) in enumerate(spans):
-        pr_c, av_c, pm_c = staged
-        jb = fast_sim.slice_jobs(jobs, lo, hi)
-        if regional:
-            if sharded:
-                out = fast_sim.simulate_pool_regions_sharded(
+    with TraceAnnotation("sched.select", jobs=n_jobs):
+        counters.add("select_calls")
+        with TraceAnnotation("sched.eg"):
+            # two programs: the fill value's conversion and its broadcast
+            counters.add("launches", 2)
+            u_sum = jnp.zeros((n_pol,), jnp.float32)
+        max_w, regrets, hist, raw = [], [], [], []
+        ent, top, sim_chunks = [], [], []
+        spans = [(lo, min(lo + chunk, n_jobs))
+                 for lo in range(0, n_jobs, chunk)]
+        staged = _stage(*spans[0])
+        for i, (lo, hi) in enumerate(spans):
+            pr_c, av_c, pm_c = staged
+            jb = fast_sim.slice_jobs(jobs, lo, hi)
+            counters.add("launches", sum(
+                isinstance(f, jax.Array) and hi - lo < f.shape[0]
+                for f in jobs))
+            if regional:
+                if sharded:
+                    out = fast_sim.simulate_pool_regions_sharded(
+                        pool_arrays, jb, tput, pr_c, av_c, pm_c,
+                        backend=backend, delta_mig=delta_mig, mesh=mesh,
+                        collect=collect, fallback=fallback, p_od=p_od,
+                    )
+                else:
+                    out = fast_sim.simulate_pool_regions(
+                        pool_arrays, jb, tput, pr_c, av_c, pm_c,
+                        backend=backend, delta_mig=delta_mig,
+                        collect=collect, fallback=fallback, p_od=p_od,
+                    )
+            elif sharded:
+                out = fast_sim.simulate_pool_jobs_sharded(
                     pool_arrays, jb, tput, pr_c, av_c, pm_c,
-                    backend=backend, delta_mig=delta_mig, mesh=mesh,
-                    collect=collect, fallback=fallback, p_od=p_od,
+                    backend=backend, mesh=mesh, collect=collect,
+                    fallback=fallback,
                 )
             else:
-                out = fast_sim.simulate_pool_regions(
+                out = fast_sim.simulate_pool_jobs(
                     pool_arrays, jb, tput, pr_c, av_c, pm_c,
-                    backend=backend, delta_mig=delta_mig, collect=collect,
-                    fallback=fallback, p_od=p_od,
+                    backend=backend, collect=collect, fallback=fallback,
                 )
-        elif sharded:
-            out = fast_sim.simulate_pool_jobs_sharded(
-                pool_arrays, jb, tput, pr_c, av_c, pm_c, backend=backend,
-                mesh=mesh, collect=collect, fallback=fallback,
-            )
-        else:
-            out = fast_sim.simulate_pool_jobs(
-                pool_arrays, jb, tput, pr_c, av_c, pm_c, backend=backend,
-                collect=collect, fallback=fallback,
-            )
-        u = out["utility"]                       # (k, M), device-resident
-        u_sum = u_sum + jnp.sum(u, axis=0)
-        state, traj = _normalize_and_scan(jb, u, state, track_history,
-                                          collect)
-        # everything above is async-dispatched device work; prep the NEXT
-        # chunk now so host prep overlaps the in-flight simulation
-        if i + 1 < len(spans):
-            staged = _stage(*spans[i + 1])
-        max_w.append(traj["max_weight"])
-        regrets.append(traj["regret"])
-        if track_history:
-            hist.append(traj["weights"])
-        if return_utilities:
-            raw.append(u)
-        if collect:
-            ent.append(traj["entropy"])
-            top.append(traj["top_policy"])
-            sim_chunks.append(out)
+            u = out["utility"]                   # (k, M), device-resident
+            with TraceAnnotation("sched.eg"):
+                counters.add("launches", 2)      # the column sum, the add
+                u_sum = u_sum + jnp.sum(u, axis=0)
+                state, traj = counters.launch(
+                    _normalize_and_scan, jb, u, state, track_history,
+                    collect)
+            # everything above is async-dispatched device work; prep the
+            # NEXT chunk now so host prep overlaps the in-flight simulation
+            if i + 1 < len(spans):
+                staged = _stage(*spans[i + 1])
+            max_w.append(traj["max_weight"])
+            regrets.append(traj["regret"])
+            if track_history:
+                hist.append(traj["weights"])
+            if return_utilities:
+                raw.append(u)
+            if collect:
+                ent.append(traj["entropy"])
+                top.append(traj["top_policy"])
+                sim_chunks.append(out)
 
-    cat = (lambda parts: np.asarray(parts[0]) if len(parts) == 1
-           else np.concatenate([np.asarray(p) for p in parts]))
-    sim_out = None
-    if collect:
-        sim_out = {k: cat([c[k] for c in sim_chunks])
-                   for k in sim_chunks[0]}
-    return SelectionResult(
-        state=state,
-        mean_utility=np.asarray(u_sum) / n_jobs,
-        max_weight=cat(max_w),
-        regret=cat(regrets),
-        n_jobs=n_jobs,
-        weight_history=cat(hist) if track_history else None,
-        utilities=cat(raw) if return_utilities else None,
-        entropy=cat(ent) if collect else None,
-        top_policy=cat(top) if collect else None,
-        sim_out=sim_out,
-    )
+        # the host waits here for the device to finish the call's work
+        with TraceAnnotation("sched.fetch"):
+            cat = (lambda parts: counters.to_host(parts[0])
+                   if len(parts) == 1
+                   else np.concatenate([counters.to_host(p) for p in parts]))
+            sim_out = None
+            if collect:
+                sim_out = {k: cat([c[k] for c in sim_chunks])
+                           for k in sim_chunks[0]}
+            return SelectionResult(
+                state=state,
+                mean_utility=counters.to_host(u_sum) / n_jobs,
+                max_weight=cat(max_w),
+                regret=cat(regrets),
+                n_jobs=n_jobs,
+                weight_history=cat(hist) if track_history else None,
+                utilities=cat(raw) if return_utilities else None,
+                entropy=cat(ent) if collect else None,
+                top_policy=cat(top) if collect else None,
+                sim_out=sim_out,
+            )

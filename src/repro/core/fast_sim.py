@@ -29,6 +29,11 @@ unsharded twins on a single device; the shard_map'd partition runners are
 built once per static config (``_sharded_pool_call``) so steady-state calls
 never retrace.
 
+The host drivers (:func:`_run_partitioned` and its sharded twin) mark their
+steps with ``sched.pool.*`` profiler spans and count their transfers and
+launches in ``repro.obs.counters``; named scopes put ``ahap_precompute`` and
+the window DP's ``window_dp`` on the device ops' names.
+
 ``simulate_one`` keeps the seed's monolithic all-kinds step (every decision
 rule evaluated at every slot, DP included) and doubles as the benchmark
 baseline via ``simulate_pool_monolithic``.
@@ -41,12 +46,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import JobConfig, ThroughputConfig
 from repro.core.job import value_fn
 from repro.core.policies import RSEL_BIG, RSEL_PRED_WINDOW
 from repro.core.policy_pool import KIND_AHAP
 from repro.core.window_opt import solve_window, solve_window_batch
+from repro.obs import counters
 
 W1MAX = 6   # max omega + 1
 VMAX = 5    # max commitment level
@@ -531,11 +538,12 @@ def _simulate_lanes_ahap(omega, v, sigma, rho, j: JobArrays, tput,
     # tensors AND their transposed copies at every scan boundary — at Fig.
     # 9/10 scale (1000 jobs x 105 AHAP lanes) that doubled the largest
     # buffers in the whole simulation for pure data movement.
-    pr, thr_s, z_exp_end, eff_slots = jax.vmap(
-        lambda t, pm: jax.vmap(
-            lambda w, s, r: _ahap_precompute(j, w, s, r, t, pm)
-        )(omega, sigma, rho)
-    )(ts, pred)
+    with jax.named_scope("ahap_precompute"):
+        pr, thr_s, z_exp_end, eff_slots = jax.vmap(
+            lambda t, pm: jax.vmap(
+                lambda w, s, r: _ahap_precompute(j, w, s, r, t, pm)
+            )(omega, sigma, rho)
+        )(ts, pred)
     # pr (dmax, P, W1MAX, 2); thr_s (dmax, P, W1MAX); rest (dmax, P)
     av_i = avail.astype(jnp.int32)
     if fallback is not None:
@@ -759,13 +767,24 @@ def _scatter_merge(parts, index_arrays, axis: int):
     """Stitch per-partition result dicts back into original pool order."""
     if len(parts) == 1:
         return parts[0]
-    order = np.argsort(np.concatenate(index_arrays), kind="stable")
-    return {
-        k: jnp.take(
-            jnp.concatenate([p[k] for p in parts], axis=axis), order, axis=axis
-        )
-        for k in parts[0]
-    }
+    with TraceAnnotation("sched.pool.merge"):
+        order = np.argsort(np.concatenate(index_arrays), kind="stable")
+        # per key: a concatenate and a take, each handing ``order`` over
+        counters.add("launches", 2 * len(parts[0]))
+        counters.h2d(order, times=len(parts[0]))
+        return {
+            k: jnp.take(
+                jnp.concatenate([p[k] for p in parts], axis=axis), order,
+                axis=axis
+            )
+            for k in parts[0]
+        }
+
+
+def _device(x):
+    """``jnp.asarray(x)``, counted as a transfer when ``x`` is on the host."""
+    counters.h2d(x)
+    return jnp.asarray(x)
 
 
 def _partition_lane_args(pool_arrays: dict, with_regions: bool):
@@ -800,15 +819,26 @@ def _run_partitioned(pool_arrays, ahap_call, cheap_call, axis: int,
     """Shared partition -> dispatch -> scatter-back driver for every
     single-device pool entry point (axis is the policy-lane axis of the
     result leaves; lane slicing in :func:`_partition_lane_args`)."""
-    ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
-        pool_arrays, with_regions
-    )
+    with TraceAnnotation("sched.pool.lanes"):
+        ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
+            pool_arrays, with_regions
+        )
+
+    def run(span, call, lane_args):
+        # each partition's lanes go over just before its program, so the
+        # AHAP program starts before the cheap lanes are sent
+        with TraceAnnotation("sched.pool.lanes"):
+            counters.h2d(lane_args)
+            lane_args = tuple(jnp.asarray(a) for a in lane_args)
+        with TraceAnnotation(span):
+            return call(*lane_args)
+
     parts, idxs = [], []
     if ahap_idx.size:
-        parts.append(ahap_call(*(jnp.asarray(a) for a in ahap_args)))
+        parts.append(run("sched.pool.ahap", ahap_call, ahap_args))
         idxs.append(ahap_idx)
     if other_idx.size:
-        parts.append(cheap_call(*(jnp.asarray(a) for a in cheap_args)))
+        parts.append(run("sched.pool.cheap", cheap_call, cheap_args))
         idxs.append(other_idx)
     return _scatter_merge(parts, idxs, axis=axis)
 
@@ -826,12 +856,13 @@ def simulate_pool(pool_arrays: dict, j: JobArrays, tput: ThroughputConfig,
     default."""
     return _run_partitioned(
         pool_arrays,
-        lambda w, v, s, r: _pool_ahap(
-            w, v, s, r, j, tput, prices, avail, pred, backend, collect,
-            fallback,
+        lambda w, v, s, r: counters.launch(
+            _pool_ahap, w, v, s, r, j, tput, prices, avail, pred, backend,
+            collect, fallback,
         ),
-        lambda k, s, c: _pool_cheap(k, s, c, j, tput, prices, avail, collect,
-                                    fallback),
+        lambda k, s, c: counters.launch(
+            _pool_cheap, k, s, c, j, tput, prices, avail, collect, fallback,
+        ),
         axis=0,
     )
 
@@ -850,12 +881,14 @@ def simulate_pool_jobs(pool_arrays: dict, jobs: JobArrays, tput: ThroughputConfi
     to the shipped program)."""
     return _run_partitioned(
         pool_arrays,
-        lambda w, v, s, r: _pool_jobs_ahap(
-            w, v, s, r, jobs, tput, prices, avail, pred, backend, collect,
+        lambda w, v, s, r: counters.launch(
+            _pool_jobs_ahap, w, v, s, r, jobs, tput, prices, avail, pred,
+            backend, collect, fallback,
+        ),
+        lambda k, s, c: counters.launch(
+            _pool_jobs_cheap, k, s, c, jobs, tput, prices, avail, collect,
             fallback,
         ),
-        lambda k, s, c: _pool_jobs_cheap(k, s, c, jobs, tput, prices, avail,
-                                         collect, fallback),
         axis=1,
     )
 
@@ -863,9 +896,12 @@ def simulate_pool_jobs(pool_arrays: dict, jobs: JobArrays, tput: ThroughputConfi
 def _pad_leading(x, pad: int):
     """Pad axis 0 by repeating the last entry ``pad`` times (dropped from the
     result after the sharded run)."""
-    x = jnp.asarray(x)
+    x = _device(x)
     if not pad:
         return x
+    # four programs: the last entry's slice, the repeat's broadcast and
+    # reshape, the concatenation
+    counters.add("launches", 4)
     return jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0)
 
 
@@ -881,7 +917,8 @@ def _sharded_pool_call(mesh, tput, backend: str, delta_mig: int,
     The cache is what keeps the sharded path's per-call cost at dispatch
     level: a fresh shard_map closure per call would retrace (and re-lower)
     the whole pool program every invocation — the prime mover of the old
-    1000-job sharded-scale regression."""
+    1000-job sharded-scale regression. Each runner has a name of its own
+    (``jit_sharded_pool_<kind>[_regions[_pod]]`` in a device trace)."""
     if ahap and with_regions:
         if has_p_od:
             def local(w, v_, s, r, rs, rm, jb, pr_, av_, pm_, po):
@@ -921,6 +958,9 @@ def _sharded_pool_call(mesh, tput, backend: str, delta_mig: int,
             return _pool_jobs_cheap(k, s, c, jb, tput, pr_, av_, collect,
                                     fallback)
         n_lane = 3
+    local.__name__ = ("sharded_pool_" + ("ahap" if ahap else "cheap")
+                      + ("_regions" if with_regions else "")
+                      + ("_pod" if has_p_od else ""))
     from jax.sharding import PartitionSpec
 
     # the tiny (R,) od-multiplier vector is replicated to every device
@@ -954,26 +994,29 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
 
     n_jobs = int(np.shape(jobs.workload)[0])
     pad_j = (-n_jobs) % n_jobs_dev
-    if pad_j:
-        jobs = JobArrays(*[_pad_leading(f, pad_j) for f in jobs])
-        prices, avail, pred = (
-            _pad_leading(x, pad_j) for x in (prices, avail, pred)
-        )
     # resolve the logical axes against the mesh (divisibility always holds
     # after padding; a non-matching mesh degrades to replication)
     rules = {**shardlib.DEFAULT_RULES, "jobs": jobs_axes}
     jspec = shardlib.resolve_spec(("jobs",), (n_jobs + pad_j,), mesh, rules)
 
-    ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
-        pool_arrays, with_regions
-    )
-    pr_j, av_j, pm_j = (jnp.asarray(x) for x in (prices, avail, pred))
-    pod_args = () if p_od is None else (jnp.asarray(p_od, jnp.float32),)
+    # operand padding and transfer, and the lane split
+    with TraceAnnotation("sched.pool.lanes"):
+        if pad_j:
+            jobs = JobArrays(*[_pad_leading(f, pad_j) for f in jobs])
+            prices, avail, pred = (
+                _pad_leading(x, pad_j) for x in (prices, avail, pred)
+            )
+        ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
+            pool_arrays, with_regions
+        )
+        pr_j, av_j, pm_j = (_device(x) for x in (prices, avail, pred))
+        pod_args = () if p_od is None else (_device(p_od),)
 
     def run_part(ahap: bool, lane_arrays):
-        p_l = int(np.shape(lane_arrays[0])[0])
-        pad_l = (-p_l) % n_lane_dev
-        lane_in = tuple(_pad_leading(a, pad_l) for a in lane_arrays)
+        with TraceAnnotation("sched.pool.lanes"):
+            p_l = int(np.shape(lane_arrays[0])[0])
+            pad_l = (-p_l) % n_lane_dev
+            lane_in = tuple(_pad_leading(a, pad_l) for a in lane_arrays)
         lspec = shardlib.resolve_spec(("lanes",), (p_l + pad_l,), mesh, rules)
         ospec = shardlib.resolve_spec(
             ("jobs", "lanes"), (n_jobs + pad_j, p_l + pad_l), mesh, rules
@@ -982,9 +1025,13 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
             mesh, tput, backend, int(delta_mig), with_regions, ahap,
             lspec, jspec, ospec, collect, fallback, p_od is not None,
         )
-        out = call(*lane_in, jobs, pr_j, av_j, pm_j, *pod_args)
-        if pad_l:
-            out = {k: v[:, :p_l] for k, v in out.items()}
+        with TraceAnnotation("sched.pool.ahap" if ahap else
+                             "sched.pool.cheap"):
+            out = counters.launch(call, *lane_in, jobs, pr_j, av_j, pm_j,
+                                  *pod_args)
+            if pad_l:
+                counters.add("launches", len(out))
+                out = {k: v[:, :p_l] for k, v in out.items()}
         return out
 
     parts, idxs = [], []
@@ -996,7 +1043,9 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
         idxs.append(other_idx)
     out = _scatter_merge(parts, idxs, axis=1)
     if pad_j:
-        out = {k: v[:n_jobs] for k, v in out.items()}
+        with TraceAnnotation("sched.pool.merge"):
+            counters.add("launches", len(out))
+            out = {k: v[:n_jobs] for k, v in out.items()}
     return out
 
 
@@ -1141,11 +1190,12 @@ def _simulate_lanes_ahap_regions(omega, v, sigma, rho, rsel, rmargin,
     # scan-xs layout — the old lane-major vmap built (P, R, dmax, ...)
     # tensors and 5-D transposed copies of them at every scan boundary.
     pred_sm = jnp.swapaxes(pred, 0, 1)           # (dmax, R, W1MAX, 2)
-    pr, thr_s, z_exp_end, eff_slots = jax.vmap(
-        lambda t, pm: jax.vmap(
-            lambda w, s, r: _ahap_precompute(j_pre, w, s, r, t, pm)
-        )(omega, sigma, rho)
-    )(ts, pred_sm)
+    with jax.named_scope("ahap_precompute"):
+        pr, thr_s, z_exp_end, eff_slots = jax.vmap(
+            lambda t, pm: jax.vmap(
+                lambda w, s, r: _ahap_precompute(j_pre, w, s, r, t, pm)
+            )(omega, sigma, rho)
+        )(ts, pred_sm)
     # pr (dmax, P, R, W1MAX, 2); thr_s (dmax, P, R, W1MAX); rest (dmax, P)
     sc = _region_scores(j, prices, av_i, pred)[:, rsel]  # (dmax, P, R)
     lane = jnp.arange(p)
@@ -1358,6 +1408,12 @@ def _as_p_od(p_od, n_regions: int):
     to (R,), an (R,) array is taken as-is."""
     if p_od is None:
         return None
+    x = p_od if isinstance(p_od, jax.Array) else np.asarray(p_od)
+    counters.h2d(x)
+    # a program each for the float32 conversion, the reshape and the
+    # broadcast, where they change anything
+    counters.add("launches", int(x.dtype != np.float32) + int(x.ndim != 1)
+                 + int(x.size != n_regions))
     return jnp.broadcast_to(
         jnp.asarray(p_od, jnp.float32).reshape(-1), (n_regions,)
     )
@@ -1393,13 +1449,13 @@ def simulate_pool_regions(pool_arrays: dict, jobs: JobArrays,
     p_od = _as_p_od(p_od, np.shape(prices)[1])
     return _run_partitioned(
         pool_arrays,
-        lambda w, v, s, r, rs, rm: _pool_jobs_ahap_regions(
-            w, v, s, r, rs, rm, jobs, tput, prices, avail, pred,
-            backend, delta_mig, collect, fallback, p_od,
+        lambda w, v, s, r, rs, rm: counters.launch(
+            _pool_jobs_ahap_regions, w, v, s, r, rs, rm, jobs, tput, prices,
+            avail, pred, backend, delta_mig, collect, fallback, p_od,
         ),
-        lambda k, s, c, rs, rm: _pool_jobs_cheap_regions(
-            k, s, c, rs, rm, jobs, tput, prices, avail, pred, delta_mig,
-            collect, fallback, p_od,
+        lambda k, s, c, rs, rm: counters.launch(
+            _pool_jobs_cheap_regions, k, s, c, rs, rm, jobs, tput, prices,
+            avail, pred, delta_mig, collect, fallback, p_od,
         ),
         axis=1, with_regions=True,
     )

@@ -323,13 +323,13 @@ def _sharded_fleet_call(mesh, tput, backend: str, n_ahap: int,
 
     jspec, rspec = P("jobs"), P()
 
-    def local(pol, jobs, arrivals, ids, prices, avail, pred):
+    def sharded_fleet_scan(pol, jobs, arrivals, ids, prices, avail, pred):
         return _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail,
                            pred, backend, n_ahap, axis_name="jobs",
                            collect=collect, fallback=fallback)
 
     return jax.jit(jax.shard_map(
-        local, mesh=mesh,
+        sharded_fleet_scan, mesh=mesh,
         in_specs=(jspec, jspec, jspec, jspec, rspec, rspec, rspec),
         out_specs=jspec, check_vma=False,
     ))

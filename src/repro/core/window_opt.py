@@ -197,6 +197,7 @@ def _solve_xla_batch(slot_cost, gain, tn: int):
     return n_tot, jnp.take_along_axis(obj, u_star[:, None], axis=1)[:, 0]
 
 
+@jax.named_scope("window_dp")
 def solve_window_batch(
     job: JobConfig,
     tput: ThroughputConfig,
@@ -223,7 +224,9 @@ def solve_window_batch(
     built per row. Every op in ``_unit_cost_table`` is elementwise in the
     job fields, so the shared-job lane path is unchanged bitwise.
 
-    Returns (n_o (B, w1), n_s (B, w1), objective (B,)).
+    Returns (n_o (B, w1), n_s (B, w1), objective (B,)). Its ops carry the
+    ``window_dp`` scope in their names, so a device trace can tell the
+    DP's time inside the AHAP scan.
     """
     assert backend in BACKENDS, backend
     prices = jnp.asarray(prices, jnp.float32)

@@ -17,7 +17,14 @@ Host side:
   metric reports (cost decomposition reconciled against reported
   utilities, preemption counts, fleet starvation incidence, selector
   convergence curves);
-* :mod:`repro.obs.report` — renders a ledger as a textual dashboard.
+* :mod:`repro.obs.report` — renders a ledger as a textual dashboard;
+* :mod:`repro.obs.counters` — the operator's view of transfer and dispatch
+  load: always-on process-wide counts of the host arrays (and bytes) the
+  selection path hands the device, the results it copies back and the
+  device programs it launches. Take :func:`counters.snapshot` before and
+  after the calls of interest and read the difference. Where the time of
+  each step goes is in the ``sched.*`` profiler spans of ``core.engine``
+  and ``core.fast_sim``, recorded only while a capture runs.
 """
 from repro.obs.frame import (
     FALLBACK_KEYS,
