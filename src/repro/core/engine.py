@@ -11,7 +11,10 @@ underneath was jitted, kind-partitioned and 2-D sharded. The engine chains
 
   prep      batched trace-window gather (market.gather_windows) + ONE
             vectorized forecast stack (predictor.noisy_matrix_batch) —
-            host numpy, but array code instead of K python constructions
+            host numpy, array code instead of K python constructions; from
+            predictor.BATCHED_SEEDING_MIN_ROWS jobs up even the K noise
+            generators are seeded in one batched pass, leaving only numpy's
+            own draw per row
   simulate  fast_sim.simulate_pool_jobs[_sharded] (jobs x lanes over the
             pool mesh)
   select    job.normalize_utility_batch + selector.run_eg_scan, fused into

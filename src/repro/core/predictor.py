@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.market import Trace, require_finite
+from repro.obs import counters
 
 NOISE_KINDS = (
     "magdep_uniform",
@@ -60,6 +61,130 @@ def true_future_batch(prices: np.ndarray, avail: np.ndarray,
     return np.stack([p[:, idx], a[:, idx]], axis=-1)
 
 
+# numpy's SeedSequence hash constants (numpy/random/bit_generator.pyx)
+_SS_INIT_A = 0x43B0D7E5
+_SS_MULT_A = 0x931E8875
+_SS_INIT_B = 0x8B51F9DD
+_SS_MULT_B = 0x58F38DED
+_SS_MIX_L = 0xCA01F9DD
+_SS_MIX_R = 0x4973F715
+_SS_POOL = 4
+_M32 = (1 << 32) - 1
+# PCG64's 128-bit LCG multiplier (numpy/random/src/pcg64/pcg64.h)
+_PCG64_MULT = 0x2360ED051FC65DA44385DF649FCCF645
+_M128 = (1 << 128) - 1
+
+# Rows from which noisy_matrix_batch seeds its generators in one batched
+# pass: below it, the pass's fixed cost (about 0.2 ms on a TPU v5e host's
+# CPU) outweighs the per-row ``default_rng`` constructions it replaces;
+# there the two paths cross between 12 and 16 rows (PERF.md §6, PR 15).
+BATCHED_SEEDING_MIN_ROWS = 16
+
+
+def _hashmix_consts(n: int, init: int, mult: int) -> list:
+    """The (xor, multiply) constants of ``n`` successive SeedSequence
+    hashmix steps: they depend on the step, not on the seed."""
+    out, h = [], init
+    for _ in range(n):
+        x = h
+        h = (h * mult) & _M32
+        out.append((np.uint32(x), np.uint32(h)))
+    return out
+
+
+# mix_entropy's 4 pool fills and 12 cross mixes, generate_state's 8 words
+_MIX_CONSTS = _hashmix_consts(_SS_POOL * _SS_POOL, _SS_INIT_A, _SS_MULT_A)
+_GEN_CONSTS = _hashmix_consts(2 * _SS_POOL, _SS_INIT_B, _SS_MULT_B)
+
+
+def _hashmix(value: np.ndarray, c) -> np.ndarray:
+    value = (value ^ c[0]) * c[1]
+    return value ^ (value >> np.uint32(16))
+
+
+def seed_sequence_state(seeds) -> np.ndarray:
+    """``np.random.SeedSequence(s).generate_state(4, np.uint64)`` for every
+    seed of an integer array at once: (K,) -> (K, 4) uint64, bitwise.
+
+    numpy's entropy mixing is uint32 arithmetic whose constants do not
+    depend on the seed, so it runs over the whole seed axis. A seed in
+    [0, 2**64) is one or two 32-bit entropy words; a missing second word
+    hashes as a zero word would, since the pool holds four. Negative seeds
+    raise the ``ValueError`` that ``default_rng`` raises."""
+    seeds = np.asarray(seeds)
+    if seeds.dtype.kind not in "iu":
+        raise TypeError(f"seeds must be integers, not {seeds.dtype}")
+    if seeds.dtype.kind == "i" and seeds.size and seeds.min() < 0:
+        raise ValueError("expected non-negative integer")
+    s = seeds.astype(np.uint64).reshape(-1)
+    words = [(s & np.uint64(_M32)).astype(np.uint32),
+             (s >> np.uint64(32)).astype(np.uint32)]
+    words += [np.zeros_like(words[0])] * (_SS_POOL - 2)
+    consts = iter(_MIX_CONSTS)
+    pool = [_hashmix(w, next(consts)) for w in words]
+    mix_l, mix_r = np.uint32(_SS_MIX_L), np.uint32(_SS_MIX_R)
+    for i_src in range(_SS_POOL):
+        for i_dst in range(_SS_POOL):
+            if i_src != i_dst:
+                r = (mix_l * pool[i_dst]
+                     - mix_r * _hashmix(pool[i_src], next(consts)))
+                pool[i_dst] = r ^ (r >> np.uint32(16))
+    state = np.stack([_hashmix(pool[i % _SS_POOL], c)
+                      for i, c in enumerate(_GEN_CONSTS)], axis=1)
+    lo, hi = state[:, 0::2].astype(np.uint64), state[:, 1::2]
+    out = lo | (hi.astype(np.uint64) << np.uint64(32))
+    return out.reshape(seeds.shape + (_SS_POOL,))
+
+
+def pcg64_states(seeds) -> list:
+    """``np.random.PCG64(s).state["state"]`` for every seed of an integer
+    array: each row's ``(state, inc)`` as Python ints, from
+    :func:`seed_sequence_state`'s words through PCG64's seeding (two LCG
+    steps)."""
+    out = []
+    for w0, w1, w2, w3 in seed_sequence_state(seeds).reshape(-1, 4).tolist():
+        inc = (((w2 << 64 | w3) << 1) | 1) & _M128
+        state = ((inc + (w0 << 64 | w1)) * _PCG64_MULT + inc) & _M128
+        out.append((state, inc))
+    return out
+
+
+def _noise_draws(seeds: np.ndarray, kind: str, shape: tuple) -> np.ndarray:
+    """(K,) + ``shape`` raw draws, row k from the stream
+    ``np.random.default_rng(seeds[k])`` gives: ``uniform(-1, 1, shape)``
+    for the uniform kinds, ``standard_t(3, shape)`` for the heavy-tail
+    ones. Counts the rows, and those seeded by the batched pass, in
+    ``repro.obs.counters`` (``forecast_rows``, ``forecast_rows_batched``)."""
+    eps = np.empty((len(seeds),) + tuple(shape))
+    uniform = kind.endswith("uniform")
+
+    def draw(gen, row):
+        if uniform:  # uniform(-1, 1) is -1 + 2 * this draw
+            gen.random(out=row)
+        else:
+            row[...] = gen.standard_t(3, shape)
+
+    batched = (len(seeds) >= BATCHED_SEEDING_MIN_ROWS
+               and seeds.dtype.kind in "iu")
+    counters.add("forecast_rows", len(seeds))
+    counters.add("forecast_rows_batched", len(seeds) if batched else 0)
+    if batched:
+        bits = np.random.PCG64(0)
+        gen = np.random.Generator(bits)
+        for row, (state, inc) in zip(eps, pcg64_states(seeds)):
+            bits.state = {"bit_generator": "PCG64",
+                          "state": {"state": state, "inc": inc},
+                          "has_uint32": 0, "uinteger": 0}
+            draw(gen, row)
+    else:
+        for row, s in zip(eps, seeds):
+            draw(np.random.default_rng(int(s)), row)
+    if uniform:
+        eps *= 2.0
+        eps -= 1.0
+    return eps
+
+
 def noisy_matrix_batch(prices: np.ndarray, avail: np.ndarray, kind: str,
                        level, seeds, horizon: int,
                        avail_max: int = 16) -> np.ndarray:
@@ -68,12 +193,15 @@ def noisy_matrix_batch(prices: np.ndarray, avail: np.ndarray, kind: str,
 
     Bitwise-equal to stacking
     ``NoisyPredictor(window_k, kind, level, seed=seeds[k]).matrix(horizon)``
-    over k (pinned in tests/test_selection_engine.py): every arithmetic op
-    is elementwise over the batch axis, and each row's noise is drawn from
-    ``np.random.default_rng(seeds[k])`` exactly as the per-job constructor
-    would — the per-seed draw is the one per-row op left (independent
-    streams have no batch API); everything around it is vectorized, which
-    is what collapses Fig. 9's per-job predictor loop into array code.
+    over k (pinned in tests/test_selection_engine.py and
+    tests/test_forecast_seeding.py): every arithmetic op is elementwise
+    over the batch axis, and each row's noise is drawn from the stream
+    ``np.random.default_rng(seeds[k])`` would give. From
+    :data:`BATCHED_SEEDING_MIN_ROWS` integer seeds up, the K streams are
+    seeded in one batched pass (:func:`pcg64_states`) and drawn by one
+    reused generator whose state is set per row; below it, each row
+    constructs its own ``default_rng``. The draw itself stays numpy's, once
+    per row (independent streams have no batch API).
 
     ``level`` may be a scalar (one noise level for every row) or a (K,)
     array of per-row levels — how the scenario grid realizes its
@@ -100,16 +228,9 @@ def noisy_matrix_batch(prices: np.ndarray, avail: np.ndarray, kind: str,
         np.broadcast_to(prices.mean(axis=1)[:, None], prices.shape),
         np.broadcast_to(avail.mean(axis=1)[:, None], avail.shape),
     ], axis=-1)  # (K, T, 2) per-row reference magnitudes
-    shape = out.shape[1:]
-    if kind.endswith("uniform"):
-        eps = np.stack([
-            np.random.default_rng(int(s)).uniform(-1, 1, shape) for s in seeds
-        ])
-    else:  # heavy-tail: Student-t(3), clipped for sanity
-        eps = np.stack([
-            np.clip(np.random.default_rng(int(s)).standard_t(3, shape), -8, 8)
-            for s in seeds
-        ]) / np.sqrt(3)
+    eps = _noise_draws(seeds, kind, out.shape[1:])
+    if not kind.endswith("uniform"):  # heavy-tail: Student-t(3), clipped
+        eps = np.clip(eps, -8, 8) / np.sqrt(3)
     if scale.ndim == 1:
         eps = eps * scale[None, None, :, None]
     else:

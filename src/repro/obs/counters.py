@@ -1,8 +1,8 @@
 """Process-wide transfer and dispatch counters of the selection path.
 
 Monotone integers, always on (an integer add costs less than a profiler
-span), counted where the work happens in ``core.engine`` and the
-``core.fast_sim`` pool drivers:
+span), counted where the work happens in ``core.engine``, the
+``core.fast_sim`` pool drivers and ``core.predictor``:
 
 * ``h2d_arrays`` / ``h2d_bytes`` -- host (numpy) arrays handed to the
   device: staged inputs, lane arguments, numpy leaves passed into a jitted
@@ -12,7 +12,10 @@ span), counted where the work happens in ``core.engine`` and the
 * ``launches`` -- device programs run: every jitted program call and every
   eager ``jnp`` op;
 * ``select_calls`` -- calls of ``engine.simulate_and_select``, to read the
-  others per call.
+  others per call;
+* ``forecast_rows`` / ``forecast_rows_batched`` -- rows of noisy forecast
+  drawn by ``predictor.noisy_matrix_batch``, and those of them whose noise
+  generators were seeded in its one batched pass.
 
 Read them as deltas of :func:`snapshot` around the calls of interest. On
 one device ``launches`` equals the programs a device trace shows run; on a

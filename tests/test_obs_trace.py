@@ -3,9 +3,9 @@ real ``jax.profiler`` capture on the CPU.
 
 * every ``sched.*`` span is recorded, the engine's inside ``sched.select``
   and the prep's inside ``sched.prep``;
-* one call's counter increases equal what the inputs' ``nbytes`` and the
-  pool's two kind partitions imply, and ``launches`` equals the programs
-  the trace saw run;
+* one call's counter increases equal what the inputs' ``nbytes``, the
+  pool's two kind partitions and the forecast rows imply, and ``launches``
+  equals the programs the trace saw run;
 * counters and results are the same with and without a capture.
 """
 import glob
@@ -23,6 +23,7 @@ from repro.core.policy_pool import (
     rand_deadline_pool,
     specs_to_arrays,
 )
+from repro.core.predictor import BATCHED_SEEDING_MIN_ROWS
 from repro.obs import counters
 
 ENGINE_SPANS = ("sched.stage", "sched.pool.lanes", "sched.pool.ahap",
@@ -137,6 +138,10 @@ def test_counters_match_the_inputs(setup, captured):
         "select_calls": 1,
         "d2h_bytes": 4 * n_pol + res.max_weight.nbytes + res.regret.nbytes
         + res.utilities.nbytes,
+        # the prep's forecast rows, and those seeded in one batched pass
+        "forecast_rows": len(prices),
+        "forecast_rows_batched": (
+            len(prices) if len(prices) >= BATCHED_SEEDING_MIN_ROWS else 0),
     }
     assert delta == want
 
