@@ -3,9 +3,10 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 From the root of a checkout. Sets up the cell named in ``BENCHMARK.json``
-(inputs from ``--seed``, one warm-up of every shape), runs back-to-back
-calls for ``--seconds``, checks sampled outputs against the plain
-reference in ``bench/ref`` and prints one JSON line last on standard
+through the entry its configuration names (``bench/entries/<entry>.py``:
+inputs from ``--seed``, one warm-up of every shape), runs back-to-back
+calls for ``--seconds``, checks sampled outputs with the entry's check
+against the plain reference and prints one JSON line last on standard
 output. ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 traces the window with the profiler and reports its per-layer metrics.
 ``setup_s`` runs from the process's start to the window's, less the TPU
@@ -83,9 +84,9 @@ def main(argv=None, root: str = ROOT, require_tpu: bool = True,
     counter = harness.CompileCounter()
 
     # ---- set-up: inputs from the seed, one warm-up per shape ----------
-    traffic = harness.Traffic(cell.config, cell.traffic, args.seed)
+    traffic = cell.entry.Traffic(cell.config, cell.traffic, args.seed)
     spans = harness.Spans(bool(args.trace))
-    driver = harness.SelectDriver(cell, traffic, devices, spans)
+    driver = cell.entry.Driver(cell, traffic, devices, spans)
     phases["inputs"] = time.perf_counter() - T_PROCESS
     # the second warm-up call starts from the state the first returned, as
     # every call of the window does
@@ -118,7 +119,7 @@ def main(argv=None, root: str = ROOT, require_tpu: bool = True,
     driver.settle(win.kept, need)
     del driver
     t_check = time.perf_counter()
-    numbers = harness.check(traffic, win.kept, samples)
+    numbers = cell.entry.check(traffic, win.kept, samples)
     correct, rows = harness.judge(numbers, cell.limits)
     check_s = time.perf_counter() - t_check
 

@@ -239,16 +239,17 @@ def test_control_fails_the_check(workload, jobs, calls):
     from bench import control, harness
 
     cell = harness.Cell.load(workload)
-    traffic = harness.Traffic(cell.config, dict(
+    entry = cell.entry
+    traffic = entry.Traffic(cell.config, dict(
         cell.traffic, jobs_per_call=jobs, check_jobs=4, check_calls=calls), 9)
-    kept = control.control_window(traffic, calls,
-                                  control.LOWER[cell.config["precision"]])
-    numbers = harness.check(traffic, kept, harness.pick(traffic, calls))
+    kept = entry.control_window(traffic, calls,
+                                control.LOWER[cell.config["precision"]])
+    numbers = entry.check(traffic, kept, harness.pick(traffic, calls))
     correct, rows = harness.judge(numbers, cell.limits)
     assert correct is False, numbers
     # the float32 reference in the program's place passes
-    kept = control.control_window(traffic, calls, np.float32)
-    numbers = harness.check(traffic, kept, harness.pick(traffic, calls))
+    kept = entry.control_window(traffic, calls, np.float32)
+    numbers = entry.check(traffic, kept, harness.pick(traffic, calls))
     assert harness.judge(numbers, cell.limits)[0] is True, numbers
 
 

@@ -46,9 +46,9 @@ def test_forecast_share_per_call(tmp_path, fresh_counters, workload, share):
     root = _cells_traffic(make_root(tmp_path / "root", SMALL_POOL))
     assert _read(root) is None                       # no call yet
     cell = harness.Cell.load(workload, root)
-    traffic = harness.Traffic(cell.config, cell.traffic, 2**31 + 7)
-    driver = harness.SelectDriver(cell, traffic, jax.devices()[:1],
-                                  harness.Spans(False))
+    traffic = cell.entry.Traffic(cell.config, cell.traffic, 2**31 + 7)
+    driver = cell.entry.Driver(cell, traffic, jax.devices()[:1],
+                               harness.Spans(False))
     for i in range(2):
         driver.call(traffic.call(i))
         c = fresh_counters.snapshot()

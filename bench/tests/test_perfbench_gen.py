@@ -61,12 +61,11 @@ def test_forecast_matches_program_stack(kind):
 def test_paper_pool_order_matches_program():
     from repro.core.policy_pool import (baseline_specs, paper_pool,
                                         rand_deadline_pool, specs_to_arrays)
-    from bench.harness import program_pool
 
     cell = Cell.load("select-paper-1000")
     want = specs_to_arrays(paper_pool() + rand_deadline_pool()
                            + baseline_specs())
-    got = program_pool(pool_lanes(cell.config["pool"]))
+    got = cell.entry.program_pool(pool_lanes(cell.config["pool"]))
     for k in ("kind", "omega", "v", "sigma", "cfrac"):
         np.testing.assert_array_equal(want[k], got[k], err_msg=k)
     assert len(got["kind"]) == 124
