@@ -176,11 +176,13 @@ def _ahanp_rule(j: JobArrays, sigma, z, t, price, av, n_prev, prev_avail):
     z_exp_prev = j.workload / j.deadline * t.astype(jnp.float32)
     z_hat = jnp.where(z_exp_prev > 0, z / z_exp_prev, 1.0)
     p_hat = price / (sigma * j.p_o)
-    n_hat = jnp.where(
-        av == 0, 0.0,
-        jnp.where(prev_avail == 0, jnp.inf,
-                  av / jnp.maximum(prev_avail, 1).astype(jnp.float32)),
-    )
+    # n_hat = av / prev_avail (0 without supply, inf after none) is only
+    # compared with 1/2 and 1, so it is compared in integers: exactly the
+    # IEEE float32 quotient's verdict, and immune to a device whose
+    # division is not correctly rounded (x / x above 1 on a TPU for some
+    # supplies in the thousands)
+    n_hat_le = lambda num: (av == 0) | ((prev_avail != 0)
+                                        & (num * av <= prev_avail))
     ahead1 = z_hat >= 1.0
     n_an = jnp.where(
         ahead1,
@@ -188,10 +190,10 @@ def _ahanp_rule(j: JobArrays, sigma, z, t, price, av, n_prev, prev_avail):
             av == 0,
             0,
             jnp.where(
-                n_hat <= 0.5,
+                n_hat_le(2),
                 jnp.maximum(n_prev // 2, j.n_min),
                 jnp.where(
-                    n_hat <= 1.0,
+                    n_hat_le(1),
                     n_prev,
                     jnp.where(p_hat > 1.0, n_prev, jnp.maximum(n_prev, av)),
                 ),

@@ -7,9 +7,10 @@ sharded over the pool mesh). Semantics are pinned bit-for-bit-in-spirit to
 the numpy parity oracle ``core.multi_job.MultiJobScheduler``:
 
   * **demand phase** — every live job's policy decides against the FULL
-    slot supply. AHAP jobs run the slot-major batched window DP
-    (``fast_sim._ahap_rule_batch`` over per-job local clocks ``t -
-    arrival``); the five cheap kinds run their vectorized rules;
+    slot supply. The AHAP rule (``fast_sim._ahap_rule_batch``, the
+    slot-major batched window DP over per-job local clocks ``t -
+    arrival``) and the five cheap kinds' vectorized rules all run over
+    every row, and each row keeps the demand of its own kind;
   * **waterfall phase** — spot demand is granted least-slack-first as a
     sort + cumulative-supply clip instead of a Python loop: with demands
     sorted by the float32 slack key (job-id tie-break), ``grant_i =
@@ -21,15 +22,28 @@ the numpy parity oracle ``core.multi_job.MultiJobScheduler``:
     N^min top-up), with arrivals/retirements gated by ``t - arrival``
     masks so jobs stream in and out without host round-trips.
 
+Rows stay in submission order and the policy kind is a mask, not a
+layout: the program's shapes depend on the job count and the horizon
+alone, so one compiled program serves every policy mix (the admitted AHAP
+count changes with every EG draw). Every row's decision is computed from
+that row's inputs only, so a row's results are bitwise what a kind-blocked
+program gives it.
+
 Sharding lays the job axis over the pool mesh's ``"jobs"`` axis (2-D
-meshes replicate over ``"lanes"``: the fleet has no lane axis). Each
-device holds an equal ``[AHAP block | cheap block]`` slice — both kind
-blocks pad to device divisibility independently, so the static AHAP split
-is uniform across shards — and the waterfall runs on an ``all_gather`` of
-(demand, slack, id), every device granting the identical global order and
-keeping its own slice. Padded jobs carry ``arrival = T`` (never live,
-demand 0), so they cannot perturb real grants in any sort position:
-sharded results are bitwise-equal to the single-device scan.
+meshes replicate over ``"lanes"``: the fleet has no lane axis). The rows
+pad to device divisibility with ``arrival = T`` sentinel jobs (never
+live, demand 0) and each device holds one contiguous slice; the waterfall
+runs on an ``all_gather`` of (demand, slack), every device granting the
+identical global order and keeping its own slice. Row ids are global row
+positions, so real jobs keep their submission ids and pads sort after
+them on a tie; pads demand nothing, so they cannot perturb real grants in
+any sort position: sharded results are bitwise-equal to the single-device
+scan.
+
+The host side marks its steps with ``sched.fleet.*`` profiler spans
+(``prep``: policy rows, market clamp and layout; ``scan``: the dispatch;
+``gather``: dropping the sharded layout's pad rows, empty on one device)
+and counts its launches, transfers and rows in ``repro.obs.counters``.
 
 Per-job policy rows come from the EG selector weights that
 ``engine.simulate_and_select`` produces (``policy_rows_from_weights`` /
@@ -43,11 +57,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-from repro.configs.base import ThroughputConfig
 from repro.core import fast_sim
 from repro.core.fast_sim import VMAX, W1MAX, JobArrays
 from repro.core.policy_pool import KIND_AHAP
+from repro.obs import counters
 
 _POLICY_KEYS = ("kind", "omega", "v", "sigma", "rho", "cfrac")
 
@@ -92,83 +107,78 @@ _TEL_FLEET = ("tel_demand", "tel_grant", "tel_slack", "tel_rank",
               "tel_starved")
 
 
-def _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail, pred,
-                backend: str, n_ahap: int, axis_name: Optional[str] = None,
+def _fleet_scan(pol, jobs, arrivals, tput, prices, avail, pred,
+                backend: str, axis_name: Optional[str] = None,
                 collect: bool = False, fallback=None):
     """One ``lax.scan`` over market slots for a fleet (shard).
 
-    ``jobs``/``arrivals``/``ids`` are (Jl,) leaves ordered ``[AHAP block |
-    cheap block]`` with the static split at ``n_ahap``; ``pol`` holds the
-    per-job policy rows in the same order. ``prices``/``avail``/``pred``
-    are the full shared market ((T,), (T,), (T, W1MAX, 2)); the present-
-    slot forecast row is pre-clamped to the pool supply by the callers.
-    Under ``shard_map`` (``axis_name="jobs"``) the waterfall all-gathers
-    (demand, slack, id) so every shard grants the identical global order.
+    ``jobs``/``arrivals`` are (Jl,) leaves in submission order (a shard's
+    contiguous slice of the global rows under ``shard_map``); ``pol`` holds
+    the per-job policy rows in the same order. Row ``i`` of the shard is
+    global row ``shard * Jl + i``, which is also its waterfall tie-break
+    id. ``prices``/``avail``/``pred`` are the full shared market ((T,),
+    (T,), (T, W1MAX, 2)); the present-slot forecast row is pre-clamped to
+    the pool supply by the callers. Under ``shard_map``
+    (``axis_name="jobs"``) the waterfall all-gathers (demand, slack) so
+    every shard grants the identical global order.
+
+    Every row runs the AHAP rule and the cheap rules; ``pol["kind"]``
+    picks which demand the row keeps, so no shape depends on the mix.
     ``collect`` (static) appends the flight-recorder series to the scan
     ys: the shared ``fast_sim._TEL_SLOTS`` slot telemetry (preemption =
     the waterfall grant fell below last slot's allocation) plus the
     ``_TEL_FLEET`` waterfall series (demand vs grant, slack, demanders-only
-    grant rank, starvation). The False branch traces the identical
-    program as before telemetry existed.
+    grant rank, starvation). The False branch traces none of it.
 
     ``fallback`` (static repro.chaos.FallbackConfig, or None) arms the
-    per-job prediction-health monitor for the AHAP block: a per-job
+    per-job prediction-health monitor for AHAP jobs: a per-job
     realized-forecast-error EWMA over the shared market forecasts, updated
     only once a job has arrived, switches that job's demand to the
     prediction-free AHANP rule while above threshold (AHANP's "previous
     availability" is the shifted supply ``sup_prev``, the convention the
-    cheap AHANP jobs already use). ``None`` traces the bitwise-identical
-    shipped program; with ``collect`` also on, the per-job
-    ``fast_sim._TEL_FALLBACK`` series join the ys (all-zero for the cheap
-    block, which consumes no predictions).
+    cheap AHANP jobs already use). With ``collect`` also on, the per-job
+    ``fast_sim._TEL_FALLBACK`` series join the ys (all-zero for the other
+    kinds, which consume no predictions).
     """
     prices = jnp.asarray(prices, jnp.float32)
     av_i = jnp.asarray(avail).astype(jnp.int32)
     dmax = prices.shape[0]
     n_jobs = arrivals.shape[0]
-    has_ahap = n_ahap > 0
-    has_cheap = n_jobs - n_ahap > 0
     ts = jnp.arange(dmax)
+    kind = pol["kind"]
+    is_ahap = kind == KIND_AHAP
     # AHANP observes last slot's availability; in the fleet every job sees
     # the shared pool, so the "previous avail" is just the shifted supply
     # (a job's first live slot sees the current supply, like the python
     # policy's first decide).
     sup_prev = jnp.concatenate([av_i[:1], av_i[:-1]])
 
-    # fallback monitor state only exists for the prediction-consuming block
-    fb_on = fallback is not None and has_ahap
+    fb_on = fallback is not None
     if fb_on:
         fb_thr = jnp.float32(fallback.threshold)
         prev1 = fast_sim._fallback_prev1(pred)            # (T, 2)
 
-    ja = fast_sim.slice_jobs(jobs, 0, n_ahap)
-    jc = fast_sim.slice_jobs(jobs, n_ahap, n_jobs)
-    if has_ahap:
-        jcfg_a = fast_sim._job_cfg(ja)
-        v_a = pol["v"][:n_ahap]
-        arr_a = arrivals[:n_ahap]
-        # scan-invariant AHAP scaffolding, slot-major like
-        # _simulate_lanes_ahap, but on per-job local clocks t - arrival
-        # (pre-arrival rows are garbage-but-finite; the plans-validity mask
-        # k <= local_t in _ahap_rule_batch keeps them out of every average)
-        pr, thr_s, z_exp_end, eff_slots = jax.vmap(
-            lambda t, pm: jax.vmap(
-                lambda jr, w, s, r, a: fast_sim._ahap_precompute(
-                    jr, w, s, r, t - a, pm
-                )
-            )(ja, pol["omega"][:n_ahap], pol["sigma"][:n_ahap],
-              pol["rho"][:n_ahap], arr_a)
-        )(ts, pred)
-    if has_cheap:
-        kind_c = pol["kind"][n_ahap:]
-        sigma_c = pol["sigma"][n_ahap:]
-        cfrac_c = pol["cfrac"][n_ahap:]
+    jcfg = fast_sim._job_cfg(jobs)
+    # scan-invariant AHAP scaffolding, slot-major like _simulate_lanes_ahap,
+    # but on per-job local clocks t - arrival (pre-arrival rows are
+    # garbage-but-finite; the plans-validity mask k <= local_t in
+    # _ahap_rule_batch keeps them out of every average)
+    pr, thr_s, z_exp_end, eff_slots = jax.vmap(
+        lambda t, pm: jax.vmap(
+            lambda jr, w, s, r, a: fast_sim._ahap_precompute(
+                jr, w, s, r, t - a, pm
+            )
+        )(jobs, pol["omega"], pol["sigma"], pol["rho"], arrivals)
+    )(ts, pred)
 
     if axis_name is None:
-        ids_all, start = ids, 0
+        start = 0
+        ids = jnp.arange(n_jobs, dtype=jnp.int32)
+        ids_all = ids
     else:
-        ids_all = jax.lax.all_gather(ids, axis_name, tiled=True)
         start = jax.lax.axis_index(axis_name) * n_jobs
+        ids = start + jnp.arange(n_jobs, dtype=jnp.int32)
+        ids_all = jax.lax.all_gather(ids, axis_name, tiled=True)
 
     h_max = tput.alpha * jobs.n_max.astype(jnp.float32) + tput.beta
 
@@ -176,58 +186,40 @@ def _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail, pred,
         if fb_on:
             z, n_prev, cost, done, T, plans, err = carry
             price, sup, sup_p, t, pr_t, thr_t, zee_t, eff_t, p1_t = xs
-        elif has_ahap:
-            z, n_prev, cost, done, T, plans = carry
-            price, sup, sup_p, t, pr_t, thr_t, zee_t, eff_t = xs
         else:
             z, n_prev, cost, done, T, plans = carry
-            price, sup, sup_p, t = xs
+            price, sup, sup_p, t, pr_t, thr_t, zee_t, eff_t = xs
         lt = t - arrivals
         live = (lt >= 0) & (lt < jobs.deadline) & ~done
 
         # ---- demand phase: every policy decides at the FULL supply
-        d_o_parts, d_s_parts = [], []
-        if has_ahap:
-            d_o_a, d_s_a, plans = fast_sim._ahap_rule_batch(
-                jcfg_a, ja, tput, v_a, backend, z[:n_ahap], lt[:n_ahap],
-                price, sup, plans, pr_t, thr_t, zee_t, eff_t,
+        ah_o, ah_s, plans = fast_sim._ahap_rule_batch(
+            jcfg, jobs, tput, pol["v"], backend, z, lt, price, sup, plans,
+            pr_t, thr_t, zee_t, eff_t,
+        )
+        pa = jnp.where(lt >= 1, sup_p, sup)
+        an_o, an_s = fast_sim._ahanp_rule(
+            jobs, pol["sigma"], z, lt, price, sup, n_prev, pa)
+        if fb_on:
+            # the monitor only accumulates once the job is watching the
+            # market (arrived); the shared error sample is scalar
+            err = jnp.where(
+                lt >= 0,
+                fast_sim._fallback_error(fallback, err, price, sup, p1_t),
+                err,
             )
-            if fb_on:
-                lta = lt[:n_ahap]
-                # the monitor only accumulates once the job is watching
-                # the market (arrived); the shared error sample is scalar
-                err = jnp.where(
-                    lta >= 0,
-                    fast_sim._fallback_error(fallback, err, price, sup, p1_t),
-                    err,
-                )
-                fb = err > fb_thr
-                pa_a = jnp.where(lta >= 1, sup_p, sup)
-                an_o, an_s = fast_sim._ahanp_rule(
-                    ja, pol["sigma"][:n_ahap], z[:n_ahap], lta, price, sup,
-                    n_prev[:n_ahap], pa_a,
-                )
-                d_o_a = jnp.where(fb, an_o, d_o_a)
-                d_s_a = jnp.where(fb, an_s, d_s_a)
-            d_o_parts.append(d_o_a)
-            d_s_parts.append(d_s_a)
-        if has_cheap:
-            ltc = lt[n_ahap:]
-            zc, npv = z[n_ahap:], n_prev[n_ahap:]
-            pa = jnp.where(ltc >= 1, sup_p, sup)
-            an_o, an_s = fast_sim._ahanp_rule(
-                jc, sigma_c, zc, ltc, price, sup, npv, pa)
-            od_o, od_s = fast_sim._od_rule(jc, tput, zc, ltc, price, sup)
-            ms_o, ms_s = fast_sim._msu_rule(jc, tput, zc, ltc, price, sup)
-            up_o, up_s = fast_sim._up_rule(jc, tput, zc, ltc, price, sup)
-            rd_o, rd_s = fast_sim._rand_rule(
-                jc, tput, cfrac_c, zc, ltc, price, sup)
-            sel = [kind_c == 1, kind_c == 2, kind_c == 3, kind_c == 4,
-                   kind_c == 5]
-            d_o_parts.append(jnp.select(sel, [an_o, od_o, ms_o, up_o, rd_o]))
-            d_s_parts.append(jnp.select(sel, [an_s, od_s, ms_s, up_s, rd_s]))
-        d_o = d_o_parts[0] if len(d_o_parts) == 1 else jnp.concatenate(d_o_parts)
-        d_s = d_s_parts[0] if len(d_s_parts) == 1 else jnp.concatenate(d_s_parts)
+            fb = is_ahap & (err > fb_thr)
+            ah_o = jnp.where(fb, an_o, ah_o)
+            ah_s = jnp.where(fb, an_s, ah_s)
+        od_o, od_s = fast_sim._od_rule(jobs, tput, z, lt, price, sup)
+        ms_o, ms_s = fast_sim._msu_rule(jobs, tput, z, lt, price, sup)
+        up_o, up_s = fast_sim._up_rule(jobs, tput, z, lt, price, sup)
+        rd_o, rd_s = fast_sim._rand_rule(
+            jobs, tput, pol["cfrac"], z, lt, price, sup)
+        sel = [is_ahap, kind == 1, kind == 2, kind == 3, kind == 4,
+               kind == 5]
+        d_o = jnp.select(sel, [ah_o, an_o, od_o, ms_o, up_o, rd_o])
+        d_s = jnp.select(sel, [ah_s, an_s, od_s, ms_s, up_s, rd_s])
         # demand clip against the full pool; dead jobs demand nothing
         d_s = jnp.clip(d_s, 0, jnp.minimum(sup, jobs.n_max))
         d_o = jnp.clip(d_o, 0, jobs.n_max - d_s)
@@ -263,17 +255,8 @@ def _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail, pred,
                 jobs, n_prev0, z, n_o, n_s, active, price, grant
             ) + (d_s, grant, jnp.where(live, slack, 0.0), rank,
                  live & (d_s > 0) & (grant < d_s))
-            if fallback is not None:
-                if fb_on:
-                    pad = (n_jobs - n_ahap,)
-                    fb_all = jnp.concatenate(
-                        [fb, jnp.zeros(pad, jnp.bool_)]) if has_cheap else fb
-                    err_all = jnp.concatenate(
-                        [err, jnp.zeros(pad, jnp.float32)]) if has_cheap else err
-                else:
-                    fb_all = jnp.zeros((n_jobs,), jnp.bool_)
-                    err_all = jnp.zeros((n_jobs,), jnp.float32)
-                ys = ys + (fb_all, err_all)
+            if fb_on:
+                ys = ys + (fb, jnp.where(is_ahap, err, 0.0))
         new_carry = (z, n_prev, cost, done, T, plans)
         if fb_on:
             new_carry = new_carry + (err,)
@@ -283,39 +266,36 @@ def _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail, pred,
         jnp.zeros((n_jobs,), jnp.float32), jnp.zeros((n_jobs,), jnp.int32),
         jnp.zeros((n_jobs,), jnp.float32), jnp.zeros((n_jobs,), jnp.bool_),
         jnp.zeros((n_jobs,), jnp.float32),
-        jnp.zeros((n_ahap, VMAX, W1MAX, 2), jnp.float32),
+        jnp.zeros((n_jobs, VMAX, W1MAX, 2), jnp.float32),
     )
-    xs = (prices, av_i, sup_prev, ts)
-    if has_ahap:
-        xs = xs + (pr, thr_s, z_exp_end, eff_slots)
+    xs = (prices, av_i, sup_prev, ts, pr, thr_s, z_exp_end, eff_slots)
     if fb_on:
-        init = init + (jnp.zeros((n_ahap,), jnp.float32),)
+        init = init + (jnp.zeros((n_jobs,), jnp.float32),)
         xs = xs + (prev1,)
     (z, _, cost, done, T, *_rest), ys = jax.lax.scan(step, init, xs)
     out = fast_sim._finalize(
-        fast_sim._job_cfg(jobs), jobs, tput, z, cost, done, T,
+        jcfg, jobs, tput, z, cost, done, T,
         jnp.swapaxes(ys[0], 0, 1), jnp.swapaxes(ys[1], 0, 1),
     )
     if collect:
         keys = fast_sim._TEL_SLOTS + _TEL_FLEET + (
-            fast_sim._TEL_FALLBACK if fallback is not None else ())
+            fast_sim._TEL_FALLBACK if fb_on else ())
         for key, hist in zip(keys, ys[2:]):
             out[key] = jnp.swapaxes(hist, 0, 1)
     return out
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "tput", "backend", "n_ahap", "collect", "fallback"))
-def _fleet_call(pol, jobs, arrivals, ids, tput, prices, avail, pred,
-                backend: str, n_ahap: int, collect: bool = False,
-                fallback=None):
-    return _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail, pred,
-                       backend, n_ahap, collect=collect, fallback=fallback)
+    "tput", "backend", "collect", "fallback"))
+def _fleet_call(pol, jobs, arrivals, tput, prices, avail, pred,
+                backend: str, collect: bool = False, fallback=None):
+    return _fleet_scan(pol, jobs, arrivals, tput, prices, avail, pred,
+                       backend, collect=collect, fallback=fallback)
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_fleet_call(mesh, tput, backend: str, n_ahap: int,
-                        collect: bool = False, fallback=None):
+def _sharded_fleet_call(mesh, tput, backend: str, collect: bool = False,
+                        fallback=None):
     """jit(shard_map)-wrapped fleet runner, cached on the static
     configuration (same reasoning as fast_sim._sharded_pool_call: a fresh
     shard_map closure per call would re-lower the whole program)."""
@@ -323,20 +303,20 @@ def _sharded_fleet_call(mesh, tput, backend: str, n_ahap: int,
 
     jspec, rspec = P("jobs"), P()
 
-    def sharded_fleet_scan(pol, jobs, arrivals, ids, prices, avail, pred):
-        return _fleet_scan(pol, jobs, arrivals, ids, tput, prices, avail,
-                           pred, backend, n_ahap, axis_name="jobs",
-                           collect=collect, fallback=fallback)
+    def sharded_fleet_scan(pol, jobs, arrivals, prices, avail, pred):
+        return _fleet_scan(pol, jobs, arrivals, tput, prices, avail, pred,
+                           backend, axis_name="jobs", collect=collect,
+                           fallback=fallback)
 
     return jax.jit(jax.shard_map(
         sharded_fleet_scan, mesh=mesh,
-        in_specs=(jspec, jspec, jspec, jspec, rspec, rspec, rspec),
+        in_specs=(jspec, jspec, jspec, rspec, rspec, rspec),
         out_specs=jspec, check_vma=False,
     ))
 
 
 # ---------------------------------------------------------------------------
-# Host-side prep: policy rows, market tensors, kind blocking
+# Host-side prep: policy rows, market tensors, layout
 # ---------------------------------------------------------------------------
 
 def _norm_rows(pool_rows):
@@ -372,9 +352,29 @@ def _prepare_market(prices, avail, pred):
     return prices, avail, pred
 
 
-def _take_jobs(jobs: JobArrays, idx) -> JobArrays:
-    idx = jnp.asarray(idx)
-    return JobArrays(*[jnp.asarray(f)[idx] for f in jobs])
+def _count_rows(kind, arrivals, deadline, n_rows: int, dmax: int):
+    """One fleet call's row counters: the rows the program computes (the
+    AHAP rule runs over all of them), those that are padding or of
+    another kind, and the (row, slot) cells scanned and inside a real
+    job's live window ``[arrival, arrival + deadline)``."""
+    arr = np.asarray(arrivals, np.int64)
+    live = (np.minimum(arr + np.asarray(deadline, np.int64), dmax)
+            - np.clip(arr, 0, dmax))
+    counters.add("fleet_calls")
+    counters.add("fleet_rows", n_rows)
+    counters.add("fleet_rows_padded",
+                 n_rows - int(np.sum(kind == KIND_AHAP)))
+    counters.add("fleet_cells", n_rows * dmax)
+    counters.add("fleet_cells_live", int(np.maximum(live, 0).sum()))
+
+
+def _trim(out, n: int):
+    """The result rows of the ``n`` submitted jobs: the leading rows, as
+    the layout keeps submission order and puts pads last."""
+    if all(v.shape[0] == n for v in out.values()):
+        return out
+    counters.add("launches", len(out))
+    return {k: v[:n] for k, v in out.items()}
 
 
 def simulate_fleet(pool_rows, jobs: JobArrays, arrivals, tput, prices,
@@ -392,25 +392,23 @@ def simulate_fleet(pool_rows, jobs: JobArrays, arrivals, tput, prices,
     Returns the ``fast_sim._finalize`` dict (utility/value/cost/
     completion_time/z_ddl/completed + (J, T) allocation histories), in
     submission order. Semantics match ``multi_job.MultiJobScheduler`` (the
-    numpy oracle): completion times are on each job's local clock.
+    numpy oracle): completion times are on each job's local clock. One
+    compiled program per (J, T, ``tput``, ``backend``, ``collect``,
+    ``fallback``), whatever the policy mix.
     """
-    rows, n = _norm_rows(pool_rows)
-    assert n == int(np.shape(jobs.workload)[0]) == int(np.shape(arrivals)[0])
-    prices, avail_np, pred = _prepare_market(prices, avail, pred)
-    aidx = np.flatnonzero(rows["kind"] == KIND_AHAP)
-    cidx = np.flatnonzero(rows["kind"] != KIND_AHAP)
-    order = np.concatenate([aidx, cidx]).astype(np.int32)
-    pos = np.argsort(order, kind="stable")
-    pol = {k: jnp.asarray(v[order]) for k, v in rows.items()}
-    out = _fleet_call(
-        pol, _take_jobs(jobs, order),
-        jnp.asarray(np.asarray(arrivals, np.int32)[order]),
-        jnp.asarray(order), tput, jnp.asarray(prices),
-        jnp.asarray(avail_np), jnp.asarray(pred), backend, len(aidx),
-        collect, fallback,
-    )
-    take = jnp.asarray(pos)
-    return {k: jnp.take(v, take, axis=0) for k, v in out.items()}
+    with TraceAnnotation("sched.fleet.prep"):
+        rows, n = _norm_rows(pool_rows)
+        assert n == int(np.shape(jobs.workload)[0]) \
+            == int(np.shape(arrivals)[0])
+        prices, avail_np, pred = _prepare_market(prices, avail, pred)
+        arr = np.asarray(arrivals, np.int32)
+        _count_rows(rows["kind"], arr, counters.to_host(jobs.deadline), n,
+                    prices.shape[0])
+    with TraceAnnotation("sched.fleet.scan"):
+        out = counters.launch(_fleet_call, rows, jobs, arr, tput, prices,
+                              avail_np, pred, backend, collect, fallback)
+    with TraceAnnotation("sched.fleet.gather"):
+        return _trim(out, n)
 
 
 def simulate_fleet_sharded(pool_rows, jobs: JobArrays, arrivals, tput,
@@ -422,10 +420,11 @@ def simulate_fleet_sharded(pool_rows, jobs: JobArrays, arrivals, tput,
     device). On a 2-D ``("jobs", "lanes")`` mesh only the ``"jobs"`` axis
     shards (the fleet has no lane axis; lanes replicate), so a lanes-only
     ``(1, n)`` mesh — like a single device — falls through to the
-    unsharded scan. Each kind block pads to device divisibility with
-    ``arrival = T`` sentinel jobs (never live, zero demand: provably
-    inert in the waterfall), and results are bitwise-equal to
-    :func:`simulate_fleet` (pinned in tests/test_fleet.py)."""
+    unsharded scan. The rows pad to device divisibility with ``arrival =
+    T`` sentinel jobs (never live, zero demand: provably inert in the
+    waterfall), one program per (J, T, static flags) whatever the mix, and
+    results are bitwise-equal to :func:`simulate_fleet` (pinned in
+    tests/test_fleet.py)."""
     from repro.launch.mesh import make_pool_mesh, pool_mesh_job_axes
 
     mesh = make_pool_mesh() if mesh is None else mesh
@@ -434,46 +433,27 @@ def simulate_fleet_sharded(pool_rows, jobs: JobArrays, arrivals, tput,
         return simulate_fleet(pool_rows, jobs, arrivals, tput, prices,
                               avail, pred, backend, collect, fallback)
 
-    rows, n = _norm_rows(pool_rows)
-    assert n == int(np.shape(jobs.workload)[0]) == int(np.shape(arrivals)[0])
-    prices, avail_np, pred = _prepare_market(prices, avail, pred)
-    dmax = prices.shape[0]
-    arr_np = np.asarray(arrivals, np.int32)
-    aidx = np.flatnonzero(rows["kind"] == KIND_AHAP)
-    cidx = np.flatnonzero(rows["kind"] != KIND_AHAP)
-    d = n_jobs_dev
-    j_a = -(-len(aidx) // d) if len(aidx) else 0   # per-device block sizes
-    j_c = -(-len(cidx) // d) if len(cidx) else 0
-
-    def block(idx, per_dev):
-        lay = np.full(d * per_dev, -1, np.int64)
-        lay[: len(idx)] = idx
-        return lay.reshape(d, per_dev)
-
-    # interleave [AHAP block | cheap block] per device: every shard gets
-    # the same static (j_a + j_c) structure with the AHAP split at j_a
-    lay = np.concatenate([block(aidx, j_a), block(cidx, j_c)], axis=1)
-    lay = lay.reshape(-1)
-    fill = np.concatenate([
-        np.full((d, j_a), aidx[0] if len(aidx) else 0, np.int64),
-        np.full((d, j_c), cidx[0] if len(cidx) else 0, np.int64),
-    ], axis=1).reshape(-1)
-    gidx = np.where(lay >= 0, lay, fill)
-    is_pad = lay < 0
-    arr_l = arr_np[gidx].copy()
-    arr_l[is_pad] = dmax                       # sentinel: never live
-    ids_l = np.where(is_pad, n + np.arange(lay.shape[0]), lay)
-
-    pol = {k: jnp.asarray(v[gidx]) for k, v in rows.items()}
-    call = _sharded_fleet_call(mesh, tput, backend, j_a, collect, fallback)
-    out = call(
-        pol, _take_jobs(jobs, gidx), jnp.asarray(arr_l),
-        jnp.asarray(ids_l.astype(np.int32)), jnp.asarray(prices),
-        jnp.asarray(avail_np), jnp.asarray(pred),
-    )
-    # rows of real ids 0..n-1 in submission order; pads (ids >= n) dropped
-    take = jnp.asarray(np.argsort(ids_l, kind="stable")[:n])
-    return {k: jnp.take(v, take, axis=0) for k, v in out.items()}
+    with TraceAnnotation("sched.fleet.prep"):
+        rows, n = _norm_rows(pool_rows)
+        assert n == int(np.shape(jobs.workload)[0]) \
+            == int(np.shape(arrivals)[0])
+        prices, avail_np, pred = _prepare_market(prices, avail, pred)
+        dmax = prices.shape[0]
+        n_rows = n_jobs_dev * -(-n // n_jobs_dev)
+        pad = n_rows - n
+        # pads repeat the first job (finite inputs) and never arrive
+        arr = np.concatenate([np.asarray(arrivals, np.int32),
+                              np.full(pad, dmax, np.int32)])
+        gidx = np.concatenate([np.arange(n), np.zeros(pad, np.int64)])
+        rows = {k: v[gidx] for k, v in rows.items()}
+        jobs = JobArrays(*[counters.to_host(f)[gidx] for f in jobs])
+        _count_rows(rows["kind"][:n], arr[:n], jobs.deadline[:n], n_rows,
+                    dmax)
+    with TraceAnnotation("sched.fleet.scan"):
+        call = _sharded_fleet_call(mesh, tput, backend, collect, fallback)
+        out = counters.launch(call, rows, jobs, arr, prices, avail_np, pred)
+    with TraceAnnotation("sched.fleet.gather"):
+        return _trim(out, n)
 
 
 # ---------------------------------------------------------------------------

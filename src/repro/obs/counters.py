@@ -1,8 +1,9 @@
-"""Process-wide transfer and dispatch counters of the selection path.
+"""Process-wide transfer and dispatch counters of the selection path and
+the fleet engine.
 
 Monotone integers, always on (an integer add costs less than a profiler
 span), counted where the work happens in ``core.engine``, the
-``core.fast_sim`` pool drivers and ``core.predictor``:
+``core.fast_sim`` pool drivers, ``core.predictor`` and ``core.fleet``:
 
 * ``h2d_arrays`` / ``h2d_bytes`` -- host (numpy) arrays handed to the
   device: staged inputs, lane arguments, numpy leaves passed into a jitted
@@ -15,7 +16,16 @@ span), counted where the work happens in ``core.engine``, the
   others per call;
 * ``forecast_rows`` / ``forecast_rows_batched`` -- rows of noisy forecast
   drawn by ``predictor.noisy_matrix_batch``, and those of them whose noise
-  generators were seeded in its one batched pass.
+  generators were seeded in its one batched pass;
+* ``fleet_calls`` -- calls of ``fleet.simulate_fleet[_sharded]``;
+* ``fleet_rows`` / ``fleet_rows_padded`` -- the rows a fleet call's AHAP
+  rule computes (every row of its layout, pads included), and those of
+  them whose result is thrown away: jobs of another kind (masked) and the
+  sharded layout's pad rows;
+* ``fleet_cells`` / ``fleet_cells_live`` -- the (row, slot) cells a fleet
+  call's scan computes, and those inside a real job's live window
+  ``[arrival, arrival + deadline)`` (a job that finishes early still
+  counts its whole window: the inputs alone say this).
 
 Read them as deltas of :func:`snapshot` around the calls of interest. On
 one device ``launches`` equals the programs a device trace shows run; on a

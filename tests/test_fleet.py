@@ -10,6 +10,13 @@ arrival/retirement masking, padded-job inertness, and the EG-weighted
 admission helpers. The multi-device half mirrors tests/test_sharded_pool —
 a subprocess forces 4 host devices and pins ``simulate_fleet_sharded``
 bitwise against the unsharded engine across mesh shapes and padding cases.
+
+One compiled program serves every policy mix: the kind is a mask over rows
+in submission order, not a layout. ``data/fleet_exact_split.npz`` holds what
+the kind-blocked engine it replaced (``[AHAP block | cheap block]`` with the
+AHAP count static, commit 1ae6872) returned on the fleets of
+:func:`_golden_inputs`; the masked program must return it bit for bit, on
+one device and sharded over four.
 """
 import os
 import subprocess
@@ -26,6 +33,7 @@ from repro.core import fast_sim, fleet  # noqa: E402
 from repro.core.market import vast_like_trace  # noqa: E402
 from repro.core.multi_job import MultiJobScheduler  # noqa: E402
 from repro.core.policy_pool import (  # noqa: E402
+    KIND_AHAP,
     baseline_specs,
     paper_pool,
     rand_deadline_pool,
@@ -35,6 +43,8 @@ from repro.core.predictor import NoisyPredictor  # noqa: E402
 
 TPUT = ThroughputConfig(mu1=0.9, mu2=0.95)
 D = 10
+GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                      "fleet_exact_split.npz")
 
 
 def _small_pool():
@@ -153,6 +163,137 @@ def test_sharded_single_device_fallback_bitwise():
 
 
 # ---------------------------------------------------------------------------
+# one program whatever the mix, bitwise the kind-blocked engine it replaced
+# ---------------------------------------------------------------------------
+
+def _golden_inputs():
+    """The fleets of the exact-split pin, by name: ``(rows, jobs,
+    arrivals, prices, avail, pred, kwargs)``. Twelve contended jobs over
+    24 slots with a mixed, an all-AHAP and an all-cheap policy draw (one
+    shape, three AHAP counts), one job alone, and the mixed fleet on a
+    storm-faulted market with the flight recorder and the armed
+    prediction-failure monitor."""
+    from repro.chaos import FallbackConfig, inject, storm_schedule
+
+    pool = _small_pool()
+    arrs = specs_to_arrays(pool)
+    kind = np.asarray(arrs["kind"])
+    ahap, cheap = (np.flatnonzero(kind == KIND_AHAP),
+                   np.flatnonzero(kind != KIND_AHAP))
+    J, T = 12, 24
+    rng = np.random.default_rng(7)
+    _, prices, avail, pred = _market(T)
+    jobs = fast_sim.stack_jobs(list(job_stream(rng, J, deadline=D)))
+    arrivals = rng.integers(0, 8, size=J)
+    mixed = rng.integers(0, len(pool), size=J)
+    market = (prices, avail, pred)
+    cases = {
+        "mixed": (mixed, jobs, arrivals, market, {}),
+        "all_ahap": (rng.choice(ahap, J), jobs, arrivals, market, {}),
+        "all_cheap": (rng.choice(cheap, J), jobs, arrivals, market, {}),
+        "one_job": (ahap[1:2], fast_sim.slice_jobs(jobs, 0, 1),
+                    np.array([2]), market, {}),
+        "storm_collect_fallback": (
+            mixed, jobs, arrivals,
+            inject(prices, avail, pred, storm_schedule(
+                1, T, n_storms=2, storm_len=5, pred_fault="stale")),
+            {"collect": True,
+             "fallback": FallbackConfig(threshold=0.5, lam=0.5)}),
+    }
+    return {name: (_rows(arrs, idx), j, a, *mk, kw)
+            for name, (idx, j, a, mk, kw) in cases.items()}
+
+
+@pytest.fixture(scope="module")
+def golden():
+    with np.load(GOLDEN) as g:
+        return {k: g[k] for k in g.files}
+
+
+def _assert_golden(out, golden, name):
+    keys = sorted(k.split("/", 1)[1] for k in golden
+                  if k.startswith(name + "/"))
+    assert sorted(out) == keys, (name, sorted(out))
+    for k in keys:
+        np.testing.assert_array_equal(np.asarray(out[k]),
+                                      golden[f"{name}/{k}"],
+                                      err_msg=f"{name} {k}")
+
+
+@pytest.mark.parametrize("name", ["mixed", "all_ahap", "all_cheap",
+                                  "one_job", "storm_collect_fallback"])
+def test_masked_layout_bitwise_matches_exact_split(name, golden):
+    rows, jobs, arrivals, prices, avail, pred, kw = _golden_inputs()[name]
+    out = fleet.simulate_fleet(rows, jobs, arrivals, TPUT, prices, avail,
+                               pred, **kw)
+    _assert_golden(out, golden, name)
+
+
+def test_one_program_whatever_the_mix():
+    """Three fleets of one shape with 0, some and all jobs on AHAP lanes,
+    each twice: the fleet program compiles for the first call only, on
+    the single-device and the sharded entry alike."""
+    import jax
+
+    cases = _golden_inputs()
+    event = "/jax/core/compile/backend_compile_duration"
+    n = [0]
+
+    def listen(name, duration, **_):
+        n[0] += name == event
+
+    def run_all(sim):
+        for name in ("all_cheap", "mixed", "all_ahap") * 2:
+            rows, jobs, arrivals, prices, avail, pred, _ = cases[name]
+            jax.block_until_ready(sim(rows, jobs, arrivals, TPUT, prices,
+                                      avail, pred)["utility"])
+
+    counts = set(int(np.sum(cases[c][0]["kind"] == KIND_AHAP))
+                 for c in ("all_cheap", "mixed", "all_ahap"))
+    assert len(counts) == 3, counts
+    fleet._fleet_call.clear_cache()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for sim in (fleet.simulate_fleet, fleet.simulate_fleet_sharded):
+            run_all(sim)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert n[0] == 1, n[0]
+
+
+def test_thousandfold_pool_matches_numpy_oracle():
+    """The benchmark's pool: the paper market's availability x 1000, far
+    above the 16-unit unit table. The device engine still matches the
+    numpy oracle, which decides with python policy objects."""
+    from repro.core.market import Trace
+
+    pool = _small_pool()
+    arrs = specs_to_arrays(pool)
+    J, T = 12, 24
+    rng = np.random.default_rng(3)
+    tr, prices, avail, pred = _market(T)
+    big = Trace(tr.prices, tr.avail * 1000)
+    avail_k = avail * 1000
+    pred_k = pred.copy()
+    pred_k[..., 1] *= 1000
+    jobs = list(job_stream(rng, J, deadline=D))
+    arrivals = rng.integers(0, 8, size=J)
+    idx = rng.integers(0, len(pool), size=J)
+    out = fleet.simulate_fleet(_rows(arrs, idx), fast_sim.stack_jobs(jobs),
+                               arrivals, TPUT, prices, avail_k, pred_k)
+    assert int(np.asarray(out["n_spot"]).sum()) > 0
+    sched = MultiJobScheduler(TPUT, big)
+    for i in range(J):
+        sched.submit(int(arrivals[i]), jobs[i], pool[int(idx[i])].build(),
+                     pred=pred_k)
+    res = {r.job_id: r for r in sched.run(T)}
+    for i in range(J):
+        np.testing.assert_allclose(
+            float(np.asarray(out["utility"])[i]), res[i].utility, atol=1e-2,
+            err_msg=f"job {i} ({pool[int(idx[i])].name})")
+
+
+# ---------------------------------------------------------------------------
 # waterfall order + masking semantics, pinned on a hand-checkable scenario
 # ---------------------------------------------------------------------------
 
@@ -250,7 +391,7 @@ def test_policy_rows_from_weights():
 # ---------------------------------------------------------------------------
 
 # Job counts 3/5/9 exercise the under-, padding- and non-dividing layouts of
-# the interleaved [AHAP | cheap] per-device blocks; the mesh list covers the
+# the per-device row slices (pads last); the mesh list covers the
 # default 1-D jobs mesh, the 2-D (jobs, lanes) mesh (fleet replicates over
 # "lanes"), lanes-only (jobs axis size 1 -> unsharded fallback) and an
 # explicit 1-D shape.
@@ -346,6 +487,40 @@ for shape in MESHES:
         np.testing.assert_array_equal(
             np.asarray(fb[k]), np.asarray(fb_sh[k]),
             err_msg=f"fleet fallback {k} mesh={shape}")
+
+# one sharded program whatever the mix: after the first fleet of a shape,
+# fleets with other AHAP counts compile nothing; and on every mesh the
+# results are bitwise those of the kind-blocked engine this one replaced
+import importlib.util
+import os
+spec = importlib.util.spec_from_file_location(
+    "test_fleet_inputs", os.environ["FLEET_TEST_MODULE"])
+tf = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(tf)
+gold = dict(np.load(tf.GOLDEN))
+cases = tf._golden_inputs()
+mesh4 = make_pool_mesh(shape=(4,))
+compiles = [0]
+
+def listen(name, duration, **_):
+    compiles[0] += name == "/jax/core/compile/backend_compile_duration"
+
+def sharded(name, mesh):
+    r, j, a, p, av, pr, kw = cases[name]
+    return fleet.simulate_fleet_sharded(r, j, a, tf.TPUT, p, av, pr,
+                                        mesh=mesh, **kw)
+
+jax.block_until_ready(sharded("all_cheap", mesh4)["utility"])
+jax.monitoring.register_event_duration_secs_listener(listen)
+for name in ("mixed", "all_ahap", "all_cheap"):
+    jax.block_until_ready(sharded(name, mesh4)["utility"])
+jax.monitoring.unregister_event_duration_listener(listen)
+assert compiles[0] == 0, compiles[0]
+for name in cases:
+    for shape in MESHES:
+        tf._assert_golden(
+            sharded(name, None if shape is None
+                    else make_pool_mesh(shape=shape)), gold, name)
 print("FLEET-SHARDED-OK")
 """
 
@@ -356,6 +531,7 @@ def test_fleet_sharded_matches_unsharded_4dev_subprocess():
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=4"
     ).strip()
     env["JAX_PLATFORMS"] = "cpu"
+    env["FLEET_TEST_MODULE"] = os.path.abspath(__file__)
     env["PYTHONPATH"] = os.pathsep.join(
         [SRC, os.path.dirname(SRC)] + env.get("PYTHONPATH", "").split(os.pathsep)
     ).rstrip(os.pathsep)

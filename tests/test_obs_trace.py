@@ -6,7 +6,9 @@ real ``jax.profiler`` capture on the CPU.
 * one call's counter increases equal what the inputs' ``nbytes``, the
   pool's two kind partitions and the forecast rows imply, and ``launches``
   equals the programs the trace saw run;
-* counters and results are the same with and without a capture.
+* counters and results are the same with and without a capture;
+* the fleet engine's ``sched.fleet.*`` spans are recorded, and one fleet
+  call's counters equal what its rows, arrivals and market imply.
 """
 import glob
 
@@ -30,6 +32,7 @@ ENGINE_SPANS = ("sched.stage", "sched.pool.lanes", "sched.pool.ahap",
                 "sched.pool.cheap", "sched.pool.merge", "sched.eg",
                 "sched.fetch")
 PREP_SPANS = ("sched.prep.windows", "sched.prep.forecast")
+FLEET_SPANS = ("sched.fleet.prep", "sched.fleet.scan", "sched.fleet.gather")
 
 
 @pytest.fixture(scope="module")
@@ -66,13 +69,9 @@ def _counted(call):
     return out, {k: v - c0.get(k, 0) for k, v in c1.items()}
 
 
-@pytest.fixture(scope="module")
-def captured(setup, tmp_path_factory):
-    """One call without a capture, then one under a capture, and what the
-    capture recorded: the sched.* host spans and the program runs."""
-    _, _, call = setup
-    plain = _counted(call)
-    d = str(tmp_path_factory.mktemp("trace"))
+def _capture(call, d):
+    """``call`` counted under a profiler capture written to ``d``, and the
+    capture's sched.* host spans and program runs."""
     jax.profiler.start_trace(d)
     try:
         traced = _counted(call)
@@ -89,6 +88,17 @@ def captured(setup, tmp_path_factory):
                 stats = {k: v for k, v in e.stats}
                 if "hlo_module" in stats and "run_id" in stats:
                     runs.add((stats["hlo_module"], stats["run_id"]))
+    return traced, spans, runs
+
+
+@pytest.fixture(scope="module")
+def captured(setup, tmp_path_factory):
+    """One call without a capture, then one under a capture, and what the
+    capture recorded: the sched.* host spans and the program runs."""
+    _, _, call = setup
+    plain = _counted(call)
+    traced, spans, runs = _capture(call,
+                                   str(tmp_path_factory.mktemp("trace")))
     return plain, traced, spans, runs
 
 
@@ -143,7 +153,10 @@ def test_counters_match_the_inputs(setup, captured):
         "forecast_rows_batched": (
             len(prices) if len(prices) >= BATCHED_SEEDING_MIN_ROWS else 0),
     }
-    assert delta == want
+    # counters another module's calls created earlier in this process
+    # (the fleet's) are in the delta too, increased by nothing
+    moved = lambda d: {k: v for k, v in d.items() if v}
+    assert moved(delta) == moved(want)
 
 
 def test_launches_are_the_programs_run(captured):
@@ -182,4 +195,72 @@ def test_sharded_runners_named_apart():
                      "sharded_pool_ahap_regions_pod",
                      "sharded_pool_cheap_regions_pod"}
     assert fleet._sharded_fleet_call(
-        mesh, PAPER_TPUT, "xla", 1).__name__ == "sharded_fleet_scan"
+        mesh, PAPER_TPUT, "xla").__name__ == "sharded_fleet_scan"
+
+
+# ---------------------------------------------------------------------------
+# the fleet engine
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def fleet_captured(tmp_path_factory):
+    """Ten contending jobs with a mixed policy draw over 16 slots: one
+    call to compile, then one counted under a capture."""
+    from repro.core import fleet
+
+    pool = (paper_pool(omegas=(1, 3), sigmas=(0.3, 0.7))
+            + rand_deadline_pool((0.3,)) + baseline_specs())
+    arrs = specs_to_arrays(pool)
+    rng = np.random.default_rng(9)
+    n, slots = 10, 16
+    jobs = job_stream_arrays(rng, n)
+    arrivals = rng.integers(0, 8, size=n).astype(np.int32)
+    idx = rng.integers(0, len(pool), size=n)
+    rows = {k: np.asarray(arrs[k])[idx] for k in fleet._POLICY_KEYS}
+    trace = paper_market(seed=21, days=2)
+    prices = trace.prices[:slots].astype(np.float32)
+    avail = trace.avail[:slots].astype(np.int64)
+
+    def call():
+        out = fleet.simulate_fleet(rows, jobs, arrivals, PAPER_TPUT, prices,
+                                   avail)
+        jax.block_until_ready(out)
+        return out
+
+    call()
+    traced, spans, runs = _capture(call,
+                                   str(tmp_path_factory.mktemp("fleet")))
+    return (rows, jobs, arrivals, prices, avail), traced, spans, runs
+
+
+def test_fleet_spans_recorded_in_order(fleet_captured):
+    _, _, spans, _ = fleet_captured
+    for name in FLEET_SPANS:
+        assert len(spans.get(name, [])) == 1, name
+    (prep,), (scan,), (gather,) = (spans[n] for n in FLEET_SPANS)
+    assert prep[1] <= scan[0] and scan[1] <= gather[0]
+
+
+def test_fleet_counters_match_the_inputs(fleet_captured):
+    (rows, jobs, arrivals, prices, avail), (_, delta), _, runs = \
+        fleet_captured
+    n, slots = len(arrivals), len(prices)
+    d = np.asarray(jobs.deadline)
+    n_ahap = int(np.sum(rows["kind"] == KIND_AHAP))
+    assert 0 < n_ahap < n                    # a mixed draw
+    host = list(rows.values()) + list(jobs) + [arrivals, prices, avail]
+    want = {
+        "fleet_calls": 1,
+        # the AHAP rule runs over every row; the other kinds' are masked
+        "fleet_rows": n,
+        "fleet_rows_padded": n - n_ahap,
+        "fleet_cells": n * slots,
+        "fleet_cells_live": int(np.sum(np.minimum(arrivals + d, slots)
+                                       - arrivals)),
+        # one program; its numpy arguments, the persistence forecast too
+        "launches": 1,
+        "h2d_arrays": len(host) + 1,
+        "h2d_bytes": sum(x.nbytes for x in host) + slots * 6 * 2 * 4,
+    }
+    assert {k: v for k, v in delta.items() if v} == want
+    assert delta["launches"] == len(runs)

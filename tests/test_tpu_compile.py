@@ -113,15 +113,15 @@ def test_pool_ahap_partition_fits_one_chip(one_chip, backend):
 
 def test_fleet_scan_fits_one_chip(one_chip):
     """The fleet contention scan at 1000 live jobs, both kind blocks."""
-    horizon, n_ahap = 15, 500
+    horizon = 15
     pol = {k: _sds((N_JOBS,), np.int32 if k in ("kind", "omega", "v")
                    else np.float32, sharding=one_chip)
            for k in fleet._POLICY_KEYS}
     i32 = lambda *s: _sds(s, np.int32, sharding=one_chip)
     compiled = fleet._fleet_call.lower(
-        pol, _jobs(N_JOBS, one_chip), i32(N_JOBS), i32(N_JOBS), PAPER_TPUT,
+        pol, _jobs(N_JOBS, one_chip), i32(N_JOBS), PAPER_TPUT,
         _sds((horizon,), np.float32, sharding=one_chip), i32(horizon),
         _sds((horizon, W1MAX, 2), np.float32, sharding=one_chip),
-        "xla", n_ahap,
+        "xla",
     ).compile()
     assert _hbm_bytes(compiled) < HBM_BYTES
