@@ -765,22 +765,34 @@ def _partition(pool_arrays: dict):
     return ahap_idx, other_idx, rho, cfrac
 
 
+@functools.lru_cache(maxsize=None)
+def _merge_program(order: bytes, axis: int):
+    """The jitted scatter-merge for one pool permutation: every result leaf
+    of every kind partition is concatenated along ``axis`` and gathered
+    back to pool order in ONE program. ``order`` (int32 bytes) is a
+    constant of the program, so a call hands the device nothing but the
+    partitions' own (device) results; jit compiles once per leaf shape."""
+    perm = np.frombuffer(order, np.int32)
+
+    def scatter_merge(parts):
+        return {
+            k: jnp.take(jnp.concatenate([p[k] for p in parts], axis=axis),
+                        perm, axis=axis)
+            for k in parts[0]
+        }
+
+    return jax.jit(scatter_merge)
+
+
 def _scatter_merge(parts, index_arrays, axis: int):
-    """Stitch per-partition result dicts back into original pool order."""
+    """Stitch per-partition result dicts back into original pool order: one
+    launch of :func:`_merge_program`, cached on the permutation and axis."""
     if len(parts) == 1:
         return parts[0]
     with TraceAnnotation("sched.pool.merge"):
         order = np.argsort(np.concatenate(index_arrays), kind="stable")
-        # per key: a concatenate and a take, each handing ``order`` over
-        counters.add("launches", 2 * len(parts[0]))
-        counters.h2d(order, times=len(parts[0]))
-        return {
-            k: jnp.take(
-                jnp.concatenate([p[k] for p in parts], axis=axis), order,
-                axis=axis
-            )
-            for k in parts[0]
-        }
+        merge = _merge_program(order.astype(np.int32).tobytes(), axis)
+        return counters.launch(merge, parts)
 
 
 def _device(x):

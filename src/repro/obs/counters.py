@@ -7,8 +7,8 @@ span), counted where the work happens in ``core.engine``, the
 
 * ``h2d_arrays`` / ``h2d_bytes`` -- host (numpy) arrays handed to the
   device: staged inputs, lane arguments, numpy leaves passed into a jitted
-  program, the scatter-merge's index array; bytes are the host array's
-  ``nbytes``;
+  program (the scatter-merge's pool order is a constant of its program
+  and is not sent); bytes are the host array's ``nbytes``;
 * ``d2h_arrays`` / ``d2h_bytes`` -- device results copied to the host;
 * ``launches`` -- device programs run: every jitted program call and every
   eager ``jnp`` op;

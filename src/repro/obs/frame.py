@@ -3,8 +3,9 @@
 The engines emit telemetry as FLAT ``tel_*`` keys in their result dicts —
 one extra stacked scan output per series, same leading axes as the
 ``n_od``/``n_spot`` histories ((P, T) per-lane, (J, P, T) pool-of-jobs,
-(J, T) fleet) — so every piece of existing result plumbing
-(``fast_sim._scatter_merge``, shard_map out_specs, padding drops, the
+(J, T) fleet) — so every piece of existing result plumbing (the one
+program of ``fast_sim._scatter_merge`` that puts every leaf of the kind
+partitions back in pool order, shard_map out_specs, padding drops, the
 fleet's submission-order reorder) carries them with zero special cases.
 This module assembles the flat keys into one NamedTuple on the host.
 

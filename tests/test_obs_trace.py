@@ -131,18 +131,16 @@ def test_counters_match_the_inputs(setup, captured):
              + arrs["kind"][cheap].nbytes + arrs["sigma"][cheap].nbytes
              + f32(cheap))                                       # cfrac
     job_bytes = sum(np.asarray(f).nbytes for f in jobs)
-    n_keys = len(fast_sim.simulate_pool_jobs(
-        arrs, jobs, PAPER_TPUT, prices, avail, preds))
-    order = 8 * n_pol                        # int64 scatter-merge order
     want = {
         # 3 staged inputs, 4 AHAP + 3 cheap lane arrays, the job leaves
-        # into both pool programs and the EG program, ``order`` per key
-        "h2d_arrays": 3 + 7 + 3 * len(jobs) + n_keys,
+        # into both pool programs and the EG program (the scatter-merge's
+        # pool order is a constant of its program: nothing is sent)
+        "h2d_arrays": 3 + 7 + 3 * len(jobs),
         "h2d_bytes": (prices.nbytes + avail.nbytes + preds.nbytes + lanes
-                      + 3 * job_bytes + n_keys * order),
-        # u_sum's zeros (2), both pool programs, a concatenate and a take
-        # per key, the column sum and the add, the EG program
-        "launches": 2 + 2 + 2 * n_keys + 2 + 1,
+                      + 3 * job_bytes),
+        # u_sum's zeros (2), both pool programs, the one scatter-merge
+        # program, the column sum and the add, the EG program
+        "launches": 2 + 2 + 1 + 2 + 1,
         # u_sum, max_weight, regret, utilities
         "d2h_arrays": 4,
         "select_calls": 1,
