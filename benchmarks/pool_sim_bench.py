@@ -3,9 +3,6 @@
 Measures slots * policies * jobs / sec over the paper's mixed workload
 (112-policy pool + 9 RAND_DEADLINE + 3 baselines, Fig. 9 job distribution):
 
-  seed         the monolithic simulator (every lane evaluates every decision
-               rule each slot, window DP included, gather-formulated DP) —
-               the state of the repo before the kind-partitioned refactor.
   partitioned  fast_sim.simulate_pool_jobs: AHAP lanes on the DP-bearing
                scan with ONE batched (P_ahap, w1, tn+1) window DP per slot,
                cheap kinds (AHANP/OD/MSU/UP/RAND_DEADLINE) on the DP-free
@@ -23,10 +20,8 @@ Measures slots * policies * jobs / sec over the paper's mixed workload
                workloads.
 
 `*_scale` rows rerun the XLA paths at the paper's Fig. 9/10 job counts
-(1000s of jobs; POOL_SIM_SCALE_JOBS to override). The seed path is not
-rerun at scale — it would take minutes; the 3x regression guard
-(tests/test_bench_regression.py) reads `speedup_partitioned_vs_seed` from
-the base workload. `pool_sim_sharded_scale_vs_partitioned` is the
+(1000s of jobs; POOL_SIM_SCALE_JOBS to override).
+`pool_sim_sharded_scale_vs_partitioned` is the
 multi-device scale ratio (partitioned_scale secs / sharded_scale secs,
 >= 1.0 means sharding pays for itself at Fig. 9/10 scale) — the guard's
 multi-device half pins it.
@@ -38,8 +33,10 @@ all devices), POOL_SIM_JSON (redirect the JSON artifact — the regression
 guard uses this so its shrunken config never clobbers the tracked
 BENCH_pool_sim.json).
 
-Writes BENCH_pool_sim.json (machine-readable rows + speedups) so successive
-PRs can track the trajectory; also returned as benchmark rows for run.py.
+Writes BENCH_pool_sim.json (machine-readable rows) so successive runs on
+one host can be compared; also returned as benchmark rows for run.py. The
+rows are timings of whatever backend runs them (XLA:CPU in CI); the chip's
+numbers are the benchmark's (bench/, PERF_LEDGER.jsonl).
 """
 from __future__ import annotations
 
@@ -48,7 +45,6 @@ import os
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import PAPER_JOB, PAPER_TPUT, Row, job_stream, paper_market
@@ -131,28 +127,7 @@ def run():
     mesh_shape = parse_pool_mesh_shape(os.environ.get("POOL_SIM_MESH", ""))
     pool_mesh = make_pool_mesh(shape=mesh_shape)
 
-    kind, omega = jnp.asarray(arrs["kind"]), jnp.asarray(arrs["omega"])
-    v_, sigma = jnp.asarray(arrs["v"]), jnp.asarray(arrs["sigma"])
-    rho, cfrac = jnp.asarray(arrs["rho"]), jnp.asarray(arrs["cfrac"])
-
-    @jax.jit
-    def _seed_jobs(jobs_, pr_, av_, pm_):
-        # the seed simulate_pool_jobs: double vmap of the monolithic lane
-        # (every lane pays the window DP, gather-formulated)
-        def per_job(jr, p_, a_, m_):
-            fn = lambda k, w, vv, s, r, c: fast_sim.simulate_one(
-                k, w, vv, s, jr, PAPER_TPUT, p_, a_, m_, rho=r, cfrac=c,
-                backend="xla-gather",
-            )
-            return jax.vmap(fn)(kind, omega, v_, sigma, rho, cfrac)
-
-        return jax.vmap(per_job)(jobs_, pr_, av_, pm_)
-
-    def seed_path():
-        return _seed_jobs(stacked, prices, avail, preds)
-
     paths = {
-        "seed": seed_path,
         "partitioned": lambda: fast_sim.simulate_pool_jobs(
             arrs, stacked, PAPER_TPUT, prices, avail, preds, backend="xla"
         ),
@@ -187,8 +162,8 @@ def run():
         rate = work_units / secs[name]
         rows.append((f"pool_sim_{name}", secs[name] * 1e6, rate))
 
-    # Fig. 9/10-scale workload (1000s of jobs): XLA paths only — the seed
-    # path at this size takes minutes and the interpreter far longer.
+    # Fig. 9/10-scale workload (1000s of jobs): XLA paths only — the
+    # interpreter would take far longer.
     scale_secs = {}
     if SCALE_REPEAT > 0 and SCALE_JOBS > 0:
         s_jobs, s_prices, s_avail, s_preds = _workload(SCALE_JOBS)
@@ -218,15 +193,6 @@ def run():
             scale_secs["partitioned_scale"] / scale_secs["sharded_scale"],
         ))
 
-    speedup = secs["seed"] / secs["partitioned"]
-    rows.append(("pool_sim_partitioned_speedup", 0.0, speedup))
-    rows.append((
-        "pool_sim_pallas_speedup", 0.0, secs["seed"] / secs["pallas"]
-    ))
-    rows.append((
-        "pool_sim_sharded_speedup", 0.0, secs["seed"] / secs["sharded"]
-    ))
-
     payload = {
         "workload": {
             "policies": n_pol, "jobs": N_JOBS, "slots": DEADLINE,
@@ -240,7 +206,6 @@ def run():
         "rows": [
             {"name": n, "us_per_call": us, "derived": d} for n, us, d in rows
         ],
-        "speedup_partitioned_vs_seed": speedup,
     }
     # benchmarks/{region_sim,selection_e2e,fleet_sim,scenario_grid}.py merge
     # their rows into the same file in place; a pool_sim rerun must carry

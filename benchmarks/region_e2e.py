@@ -11,7 +11,7 @@ same selection decision:
            chunk's (K, R, d) market tensors + (K, R, d, W1MAX, 2)
            forecast stack (double-buffered: chunk k+1's host prep
            overlaps chunk k's async-dispatched device work), the
-           simulate leg is ``simulate_pool_regions_sharded``, and the
+           simulate leg is ``simulate_pool_jobs_sharded``, and the
            fused normalize + EG lax.scan keeps the (K, M) utility matrix
            device-resident end to end.
   loop     the pre-engine pipeline: per-job ``RegionalPredictor`` /
@@ -114,7 +114,7 @@ def _loop_pipeline(market, jobs_cfg, t0s, seeds, arrs):
                 tr, KIND, LEVEL, seed=int(s) * 1009 + r)
         )
         preds.append(rp.matrix(fast_sim.W1MAX - 1)[:, :DEADLINE])
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs(jobs_cfg), PAPER_TPUT,
         np.stack(prices).astype(np.float32),
         np.stack(avail).astype(np.int64),

@@ -12,7 +12,7 @@ Comparators:
                   strongest thing a single-region scheduler could do.
   region lanes    the same slate crossed with region-selection strategies
                   (greedy-price / greedy-avail / predicted-horizon, plain
-                  and sticky) via fast_sim.simulate_pool_regions, paying
+                  and sticky) via fast_sim.simulate_pool_jobs, paying
                   ``delta_mig`` checkpoint-transfer slots per move.
 
 The headline `region_sim_gain` row is (best region lane - best single
@@ -103,7 +103,7 @@ def run():
     from repro.launch.mesh import make_pool_mesh, parse_pool_mesh_shape
 
     # same mesh knob as pool_sim_bench: the sharded region entry falls back
-    # bitwise to simulate_pool_regions on one device, so the headline gain
+    # bitwise to simulate_pool_jobs on one device, so the headline gain
     # numbers are identical either way — only the throughput row scales
     mesh = make_pool_mesh(
         shape=parse_pool_mesh_shape(os.environ.get("POOL_SIM_MESH", ""))
@@ -127,7 +127,7 @@ def run():
         single_util[r] = float(u.max())
         best_single = max(best_single, single_util[r])
 
-    region_fn = lambda: fast_sim.simulate_pool_regions_sharded(
+    region_fn = lambda: fast_sim.simulate_pool_jobs_sharded(
         r_arrs, stacked, PAPER_TPUT, prices, avail, preds,
         delta_mig=DELTA_MIG, mesh=mesh,
     )

@@ -197,9 +197,9 @@ def storm_schedule(seed: int, n_slots: int, *, n_storms: int = 2,
 def blackout_schedule(seed: int, n_slots: int, n_regions: int, *,
                       n_events: int = 1,
                       length: int = 4) -> Tuple[FaultSpec, ...]:
-    """Seeded regional-blackout schedule for ``simulate_pool_regions*``
-    markets: ``n_events`` windows, each zeroing one seeded region's
-    availability for ``length`` slots."""
+    """Seeded regional-blackout schedule for region-axis
+    ``simulate_pool_jobs*`` markets: ``n_events`` windows, each zeroing one
+    seeded region's availability for ``length`` slots."""
     rng = np.random.default_rng(seed)
     faults = []
     for _ in range(n_events):

@@ -109,7 +109,7 @@ def prepare_noisy_inputs_regions(market, t0s, deadline: int, kind: str,
     (K, R, d, W1MAX, 2) forecast stack in ONE batched pass over the
     flattened (K*R,) row axis. Returns ``(prices (K, R, d) f32, avail
     (K, R, d) i64, preds (K, R, d, W1MAX, 2) f32)`` ready for
-    ``simulate_pool_regions[_sharded]`` / the regional
+    ``fast_sim.simulate_pool_jobs[_sharded]`` / the regional
     :func:`simulate_and_select` path.
 
     Row (k, r) is seeded ``seeds[k] * 1009 + r`` — the same
@@ -269,18 +269,19 @@ def simulate_and_select(
     static-flag discipline and compiles the identical shipped program
     (pinned in tests/test_chaos.py).
 
-    **Regional mode** — pass ``delta_mig`` (the market's checkpoint-
-    transfer cost) to select among region-aware lanes instead: the inputs
-    become (K, R, d) ``prices``/``avail`` and (K, R, d, W1MAX, 2) ``preds``
-    (:func:`prepare_noisy_inputs_regions`), the simulate leg becomes
-    ``simulate_pool_regions[_sharded]``, and the (K, M) utility matrix,
-    region paths and migration counts stay device-resident between the
-    sim, normalize and EG stages exactly as in the single-region path.
-    ``p_od`` forwards the market's optional per-region on-demand
-    multipliers. With R == 1 (and ``p_od=None``) the result is
-    BITWISE-identical to the single-region engine on the squeezed inputs
-    (pinned in tests/test_region_engine.py) — the per-cell programs agree
-    bitwise and the select leg is shared code.
+    **Regional mode** — (K, R, d) ``prices``/``avail`` and
+    (K, R, d, W1MAX, 2) ``preds`` (:func:`prepare_noisy_inputs_regions`)
+    select among region-aware lanes instead, and then need ``delta_mig``
+    (the market's checkpoint-transfer cost; it and ``p_od`` are refused
+    with flat inputs). The simulate leg runs the same entry point's
+    region-aware scans, and the (K, M) utility matrix, region paths and
+    migration counts stay device-resident between the sim, normalize and
+    EG stages exactly as in the single-region path. ``p_od`` forwards the
+    market's optional per-region on-demand multipliers. With R == 1 (and
+    ``p_od=None``) the result is BITWISE-identical to the single-region
+    engine on the squeezed inputs (pinned in tests/test_region_engine.py)
+    — the per-cell programs agree bitwise and the select leg is shared
+    code.
 
     ``prep`` optionally streams input construction: a callable
     ``prep(lo, hi) -> (prices, avail, preds)`` producing each chunk's
@@ -299,7 +300,6 @@ def simulate_and_select(
     chunk = int(job_chunk) if job_chunk else n_jobs
     if chunk < 1:
         raise ValueError(f"job_chunk must be >= 1, got {job_chunk}")
-    regional = delta_mig is not None
     if prep is None and preds is None:
         raise ValueError("pass (prices, avail, preds) arrays or prep=")
 
@@ -335,29 +335,17 @@ def simulate_and_select(
             counters.add("launches", sum(
                 isinstance(f, jax.Array) and hi - lo < f.shape[0]
                 for f in jobs))
-            if regional:
-                if sharded:
-                    out = fast_sim.simulate_pool_regions_sharded(
-                        pool_arrays, jb, tput, pr_c, av_c, pm_c,
-                        backend=backend, delta_mig=delta_mig, mesh=mesh,
-                        collect=collect, fallback=fallback, p_od=p_od,
-                    )
-                else:
-                    out = fast_sim.simulate_pool_regions(
-                        pool_arrays, jb, tput, pr_c, av_c, pm_c,
-                        backend=backend, delta_mig=delta_mig,
-                        collect=collect, fallback=fallback, p_od=p_od,
-                    )
-            elif sharded:
+            if sharded:
                 out = fast_sim.simulate_pool_jobs_sharded(
                     pool_arrays, jb, tput, pr_c, av_c, pm_c,
                     backend=backend, mesh=mesh, collect=collect,
-                    fallback=fallback,
+                    fallback=fallback, delta_mig=delta_mig, p_od=p_od,
                 )
             else:
                 out = fast_sim.simulate_pool_jobs(
                     pool_arrays, jb, tput, pr_c, av_c, pm_c,
                     backend=backend, collect=collect, fallback=fallback,
+                    delta_mig=delta_mig, p_od=p_od,
                 )
             u = out["utility"]                   # (k, M), device-resident
             with TraceAnnotation("sched.eg"):

@@ -16,27 +16,27 @@ Pallas backends) instead of vmap's per-lane grid batching. All other kinds
 window DP, and the results are scattered back to the original pool order —
 the public API and semantics are unchanged.
 
+Each lane family has one scan, and the market's rank decides its region
+axis: flat (d_max,) market rows trace the single-market program, (R,
+d_max) rows add per-slot region selection (SkyNomad arXiv:2601.06520) —
+the flat market is the region-less case, not a second scan.
+
 Multi-device: ``simulate_pool_jobs_sharded`` lays the (jobs x lanes) grid
 over a mesh (repro.launch.mesh.make_pool_mesh) with ``shard_map``. On the
 default 1-D mesh jobs ride the single axis; a 2-D ``("jobs", "lanes")``
 mesh (``make_pool_mesh(shape=(a, b))``) additionally shards each kind
 partition's policy-lane axis — because the kind partition splits DP-heavy
 AHAP lanes from cheap lanes *before* sharding, every lane shard carries a
-uniform workload (load balance is by construction). Both entry points
-(``simulate_pool_jobs_sharded``, ``simulate_pool_regions_sharded``) pad
-both grid axes to divisibility and fall back bitwise-identically to their
-unsharded twins on a single device; the shard_map'd partition runners are
-built once per static config (``_sharded_pool_call``) so steady-state calls
-never retrace.
+uniform workload (load balance is by construction). Both grid axes pad to
+divisibility, and a single device falls back bitwise-identically to
+``simulate_pool_jobs``; the shard_map'd partition runners are built once
+per static config (``_sharded_pool_call``) so steady-state calls never
+retrace.
 
 The host drivers (:func:`_run_partitioned` and its sharded twin) mark their
 steps with ``sched.pool.*`` profiler spans and count their transfers and
 launches in ``repro.obs.counters``; named scopes put ``ahap_precompute`` and
 the window DP's ``window_dp`` on the device ops' names.
-
-``simulate_one`` keeps the seed's monolithic all-kinds step (every decision
-rule evaluated at every slot, DP included) and doubles as the benchmark
-baseline via ``simulate_pool_monolithic``.
 """
 from __future__ import annotations
 
@@ -51,7 +51,8 @@ from jax.profiler import TraceAnnotation
 from repro.configs.base import JobConfig, ThroughputConfig
 from repro.core.job import value_fn
 from repro.core.policies import RSEL_BIG, RSEL_PRED_WINDOW
-from repro.core.policy_pool import KIND_AHAP
+from repro.core.policy_pool import (KIND_AHANP, KIND_AHAP, KIND_MSU, KIND_OD,
+                                    KIND_RAND, KIND_UP)
 from repro.core.window_opt import solve_window, solve_window_batch
 from repro.obs import counters
 
@@ -114,12 +115,12 @@ def _sim_clip(n_o, n_s, avail, j: JobArrays):
 
 
 # ---------------------------------------------------------------------------
-# Decision rules — shared between the monolithic and kind-partitioned scans
+# Decision rules — shared by the lane scans and the fleet
 # ---------------------------------------------------------------------------
 
 def _ahap_precompute(j: JobArrays, omega, sigma, rho, ts, pred):
     """Scan-invariant AHAP scaffolding, vectorized over a leading slot axis
-    (or scalar ts/per-slot pred in the monolithic per-step path).
+    (or scalar ts with one slot's pred).
 
     Robust-AHAP discounts *predicted* availability (entries j >= 1 only)."""
     disc_av = jnp.floor(rho * pred[..., 1]).at[..., 0].set(pred[..., 0, 1])
@@ -286,6 +287,26 @@ def _rand_rule(j: JobArrays, tput, cfrac, z, t, price, av):
     return rd_o, rd_s
 
 
+_CHEAP_KINDS = (KIND_AHANP, KIND_OD, KIND_MSU, KIND_UP, KIND_RAND)
+
+
+def _cheap_rules(kind, j: JobArrays, tput, sigma, cfrac, z, t, price, av,
+                 n_prev, prev_avail):
+    """Every DP-free kind's rule, in ``_CHEAP_KINDS`` order — the one place
+    a kind id is mapped to its rule. Returns ``(conds, n_o candidates,
+    n_s candidates)`` for ``jnp.select``; ``conds()`` builds the ``kind ==
+    k`` masks, once for each select that takes them."""
+    cands = (
+        _ahanp_rule(j, sigma, z, t, price, av, n_prev, prev_avail),
+        _od_rule(j, tput, z, t, price, av),
+        _msu_rule(j, tput, z, t, price, av),
+        _up_rule(j, tput, z, t, price, av),
+        _rand_rule(j, tput, cfrac, z, t, price, av),
+    )
+    conds = lambda: [kind == k for k in _CHEAP_KINDS]
+    return conds, [o for o, _ in cands], [s for _, s in cands]
+
+
 def _execute(j: JobArrays, tput, z, n_prev, cost, done, T, t, n_o, n_s,
              price, av):
     """Mirror of simulate()'s slot execution: hard clip, mu, billing,
@@ -402,65 +423,71 @@ def _finalize(jcfg, j: JobArrays, tput, z, cost, done, T, no_hist, ns_hist):
 
 
 # ---------------------------------------------------------------------------
-# Monolithic single-lane scan (seed path; benchmark baseline)
+# Region selection (BEYOND-PAPER, SkyNomad arXiv:2601.06520)
 # ---------------------------------------------------------------------------
+#
+# Given (R, d_max) market rows, the lane scans layer per-slot region
+# selection over the unmodified decision rules: every lane carries a
+# current-region state, scores all regions each slot (vectorized, from data
+# precomputed outside the scan), switches with a hysteresis margin, pays
+# ``delta_mig`` zero-allocation slots per switch (checkpoint transfer), and
+# feeds the selected region's (price, avail, forecast) into the rules. With
+# R == 1 the selector can never leave region 0 and every migration branch is
+# a no-op ``where`` passthrough, so results are BITWISE-identical to the
+# flat market's (pinned in tests/test_region_sim.py).
 
-def simulate_one(
-    kind, omega, v, sigma,                 # policy encoding (scalars)
-    j: JobArrays,
-    tput: ThroughputConfig,
-    prices, avail, pred,                   # (dmax,), (dmax,), (dmax, W1MAX, 2)
-    rho=jnp.float32(1.0),                  # Robust-AHAP availability discount
-    cfrac=jnp.float32(0.0),                # RAND_DEADLINE commitment fraction
-    backend: str = "xla",                  # window-DP backend (static)
-):
-    """All six decision rules at every slot, selected by ``kind`` — the
-    seed formulation. The pool entry points below partition by kind instead
-    and only fall back to this for the monolithic baseline."""
-    dmax = prices.shape[0]
-    jcfg = _job_cfg(j)
+# the pred_horizon score averages a fixed-width forecast window; the python
+# reference (policies.RegionSelector.scores) pads/trims to the same width
+assert RSEL_PRED_WINDOW == W1MAX
 
-    def step(carry, xs):
-        z, n_prev, cost, done, T, plans, prev_avail = carry
-        price, av, pr_raw, t = xs  # scalar, scalar, (W1MAX, 2), scalar
 
-        pr, thr_s, z_exp_end, eff_slots = _ahap_precompute(
-            j, omega, sigma, rho, t, pr_raw
-        )
-        ah_o, ah_s, plans = _ahap_rule(
-            jcfg, j, tput, v, backend, z, t, price, av, plans,
-            pr, thr_s, z_exp_end, eff_slots,
-        )
-        an_o, an_s = _ahanp_rule(j, sigma, z, t, price, av, n_prev, prev_avail)
-        od_o, od_s = _od_rule(j, tput, z, t, price, av)
-        ms_o, ms_s = _msu_rule(j, tput, z, t, price, av)
-        up_o, up_s = _up_rule(j, tput, z, t, price, av)
-        rd_o, rd_s = _rand_rule(j, tput, cfrac, z, t, price, av)
+def _region_scores(j: JobArrays, prices, avail, pred):
+    """(dmax, 4, R) lower-better scores from (R, dmax) market data and
+    (R, dmax, W1MAX, 2) forecasts — the jnp twin of
+    policies.RegionSelector.scores, all four RSEL_* strategies at once
+    (lanes gather their row by ``rsel``). Scan-invariant: computed once per
+    (job, trace)."""
+    nmin_f = j.n_min.astype(jnp.float32)
+    dead = (avail < j.n_min).astype(jnp.float32)
+    price_sc = prices + RSEL_BIG * dead                   # (R, dmax)
+    avail_sc = -avail.astype(jnp.float32)
+    pdead = (pred[..., 1] < nmin_f).astype(jnp.float32)
+    pred_sc = jnp.mean(pred[..., 0] + RSEL_BIG * pdead, axis=-1)
+    sc = jnp.stack(
+        [jnp.zeros_like(price_sc), price_sc, avail_sc, pred_sc]
+    )                                                     # (4, R, dmax)
+    return jnp.transpose(sc, (2, 0, 1))
 
-        n_o = jnp.select(
-            [kind == 0, kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [ah_o, an_o, od_o, ms_o, up_o, rd_o],
-        )
-        n_s = jnp.select(
-            [kind == 0, kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [ah_s, an_s, od_s, ms_s, up_s, rd_s],
-        )
-        z, n_prev, cost, done, T, n_o, n_s, active = _execute(
-            j, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
-        )
-        prev_avail = jnp.where(active, av, prev_avail)
-        return (z, n_prev, cost, done, T, plans, prev_avail), (n_o, n_s)
 
-    init = (
-        jnp.float32(0.0), jnp.int32(0), jnp.float32(0.0),
-        jnp.bool_(False), jnp.float32(0.0),
-        jnp.zeros((VMAX, W1MAX, 2), jnp.float32), avail[0].astype(jnp.int32),
+def _region_step(cur, mig_left, sc_row, rmargin, delta_mig: int, inactive):
+    """One slot of region selection: argmin with hysteresis + migration
+    bookkeeping. Batched over lanes (cur/mig_left (P,), sc_row (P, R)) or
+    scalar (cur/mig_left scalars, sc_row (R,)). Returns
+    (cur, mig_left, migrating, switched); ``migrating`` slots execute with
+    zero instances (the checkpoint is in transit). ``inactive`` lanes
+    (completed, or past their deadline in a heterogeneous-deadline batch)
+    never switch — the reference loop has stopped by then, so late score
+    flips must not move (or count against) such a job."""
+    best = jnp.argmin(sc_row, axis=-1).astype(jnp.int32)
+    cur_sc = jnp.take_along_axis(sc_row, cur[..., None], -1)[..., 0]
+    best_sc = jnp.take_along_axis(sc_row, best[..., None], -1)[..., 0]
+    switch = ((best != cur) & (best_sc + rmargin < cur_sc)
+              & (mig_left == 0) & ~inactive)
+    cur = jnp.where(switch, best, cur)
+    mig_left = jnp.where(
+        switch, jnp.int32(delta_mig), jnp.maximum(mig_left - 1, 0)
     )
-    (z, _, cost, done, T, _, _), (no_hist, ns_hist) = jax.lax.scan(
-        step, init,
-        (prices, avail.astype(jnp.int32), pred, jnp.arange(dmax)),
-    )
-    return _finalize(jcfg, j, tput, z, cost, done, T, no_hist, ns_hist)
+    return cur, mig_left, mig_left > 0, switch
+
+
+def _occupied(j: JobArrays, jcfg, p_od, cur):
+    """The job as billed in region ``cur``: its on-demand price scaled by
+    the region's ``p_od`` multiplier; unchanged (no ops traced) without
+    one."""
+    if p_od is None:
+        return j, jcfg
+    j = j._replace(p_o=j.p_o * p_od[cur])
+    return j, _job_cfg(j)
 
 
 # ---------------------------------------------------------------------------
@@ -511,7 +538,8 @@ def _ahap_rule_batch(jcfg, j: JobArrays, tput, v, backend, z, t, price, av,
 
 def _simulate_lanes_ahap(omega, v, sigma, rho, j: JobArrays, tput,
                          prices, avail, pred, backend: str,
-                         collect: bool = False, fallback=None):
+                         collect: bool = False, fallback=None, rsel=None,
+                         rmargin=None, delta_mig=None, p_od=None):
     """All AHAP lanes in ONE scan over slots. Each scan slot issues a single
     batched (P_ahap, w1, tn+1) window DP instead of relying on vmap's
     per-lane grid batching (``_simulate_one_ahap`` under vmap — kept below
@@ -522,69 +550,105 @@ def _simulate_lanes_ahap(omega, v, sigma, rho, j: JobArrays, tput,
 
     ``fallback`` (a static :class:`repro.chaos.FallbackConfig`, or None)
     arms the online prediction-health monitor: the scan carries a
-    realized-forecast-error EWMA (one scalar — every lane of a job reads
-    the same forecast stack) and, while it exceeds the threshold, every
+    realized-forecast-error EWMA and, while it exceeds the threshold, the
     lane's decision is taken from the prediction-free AHANP rule instead
     of the window solve (the AHANP "previous availability" is the shifted
     supply, matching the fleet engine's convention). Plans keep updating
     underneath so recovery resumes AHAP with a warm history. ``None``
     traces the bitwise-identical shipped program; with collect also on,
-    the ``_TEL_FALLBACK`` series join the ys."""
-    dmax = prices.shape[0]
+    the ``_TEL_FALLBACK`` series join the ys.
+
+    Market rows with a region axis — prices/avail (R, dmax), pred
+    (R, dmax, W1MAX, 2) — make this the regional scan: the scaffolding is
+    precomputed per (lane, region, slot), each slot selects a region per
+    lane (``rsel``/``rmargin`` strategy, ``delta_mig`` lost slots per
+    switch) and gathers that region's row before the CHC rule, and the
+    result gains ``region`` and ``migrations`` (under collect, the
+    ``_TEL_REGION`` pair too). The fallback EWMA is then per lane (P,):
+    lanes occupy different regions, so each scores the 1-step-ahead
+    forecast of ITS region (flat rows share one scalar — every lane of a
+    job reads the same forecast stack). ``p_od`` (traced (R,) array, or
+    None) scales the on-demand price per region (termination billing uses
+    the lane's final region)."""
+    regional = prices.ndim == 2
+    dmax = prices.shape[-1]
     p = omega.shape[0]
     jcfg = _job_cfg(j)
     ts = jnp.arange(dmax)
+    # per-region od price: thr_s thresholds see the (R, 1) effective price
+    # broadcast against the (R, W1MAX) forecast rows
+    j_pre = j if p_od is None else j._replace(p_o=j.p_o * p_od[:, None])
     # slot-major from the start: slots on the OUTER vmap, lanes inner, so the
     # scan-xs layout (dmax leading) is the only one ever materialized. The
     # old lane-major vmap + per-tensor swapaxes built the (P, dmax, ...)
     # tensors AND their transposed copies at every scan boundary — at Fig.
     # 9/10 scale (1000 jobs x 105 AHAP lanes) that doubled the largest
-    # buffers in the whole simulation for pure data movement.
+    # buffers in the whole simulation for pure data movement. A regional
+    # forecast stack is transposed ONCE (small) to (dmax, R, W1MAX, 2).
     with jax.named_scope("ahap_precompute"):
         pr, thr_s, z_exp_end, eff_slots = jax.vmap(
             lambda t, pm: jax.vmap(
-                lambda w, s, r: _ahap_precompute(j, w, s, r, t, pm)
+                lambda w, s, r: _ahap_precompute(j_pre, w, s, r, t, pm)
             )(omega, sigma, rho)
-        )(ts, pred)
-    # pr (dmax, P, W1MAX, 2); thr_s (dmax, P, W1MAX); rest (dmax, P)
+        )(ts, jnp.swapaxes(pred, 0, 1) if regional else pred)
+    # pr (dmax, P, [R,] W1MAX, 2); thr_s (dmax, P, [R,] W1MAX); rest (dmax, P)
     av_i = avail.astype(jnp.int32)
+    market = (prices, av_i)                      # slot-major scan xs
+    if regional:
+        sc = _region_scores(j, prices, av_i, pred)[:, rsel]  # (dmax, P, R)
+        lane = jnp.arange(p)
+        market = (jnp.swapaxes(prices, 0, 1), jnp.swapaxes(av_i, 0, 1))
     if fallback is not None:
         thr = jnp.float32(fallback.threshold)
-        prev1 = _fallback_prev1(pred)                   # (dmax, 2)
-        prev_av = jnp.concatenate([av_i[:1], av_i[:-1]])
+        prev1 = (jnp.swapaxes(jax.vmap(_fallback_prev1)(pred), 0, 1)
+                 if regional else _fallback_prev1(pred))   # (dmax, [R,] 2)
+        prev_av = jnp.concatenate([market[1][:1], market[1][:-1]])
 
     def step(carry, xs):
+        (z, n_prev, cost, done, T, plans), reg, fb_c = carry
+        price, av, pr_t, thr_t, zee_t, eff_t, reg_xs, t, fb_xs = xs
+        j_t, jcfg_t = j, jcfg
+        reg_ys = ()
+        if regional:
+            (cur, mig_left), (sc_t,) = reg, reg_xs
+            cur, mig_left, migrating, switch = _region_step(
+                cur, mig_left, sc_t, rmargin, delta_mig,
+                done | (t >= j.deadline),
+            )
+            price, av = price[cur], av[cur]      # (P,) per-lane region rows
+            pr_t, thr_t = pr_t[lane, cur], thr_t[lane, cur]
+            j_t, jcfg_t = _occupied(j, jcfg, p_od, cur)
+            reg, reg_ys = (cur, mig_left), (cur, switch)
         if fallback is not None:
-            z, n_prev, cost, done, T, plans, err = carry
-            price, av, pr_t, thr_t, zee_t, eff_t, t, p1_t, pav_t = xs
-            err = _fallback_error(fallback, err, price, av, p1_t)
-            fb = err > thr
-        else:
-            z, n_prev, cost, done, T, plans = carry
-            price, av, pr_t, thr_t, zee_t, eff_t, t = xs
+            p1_t, pav_t = fb_xs
+            if regional:
+                p1_t, pav_t = p1_t[cur].T, pav_t[cur]  # lane-region rows
+            err = _fallback_error(fallback, *fb_c, price, av, p1_t)
+            fb, fb_c = err > thr, (err,)
         n_o, n_s, plans = _ahap_rule_batch(
-            jcfg, j, tput, v, backend, z, t, price, av, plans,
+            jcfg_t, j_t, tput, v, backend, z, t, price, av, plans,
             pr_t, thr_t, zee_t, eff_t,
         )
         if fallback is not None:
-            an_o, an_s = _ahanp_rule(j, sigma, z, t, price, av, n_prev, pav_t)
+            an_o, an_s = _ahanp_rule(j_t, sigma, z, t, price, av, n_prev,
+                                     pav_t)
             n_o = jnp.where(fb, an_o, n_o)
             n_s = jnp.where(fb, an_s, n_s)
+        if regional:
+            n_o = jnp.where(migrating, 0, n_o)
+            n_s = jnp.where(migrating, 0, n_s)
         n_prev0 = n_prev
         z, n_prev, cost, done, T, n_o, n_s, active = _execute(
-            j, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
+            j_t, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
         )
-        ys = (n_o, n_s)
+        ys = (n_o, n_s) + reg_ys
         if collect:
-            ys = ys + _slot_telemetry(j, n_prev0, z, n_o, n_s, active,
-                                      price, av)
+            ys = ys + _slot_telemetry(j_t, n_prev0, z, n_o, n_s, active,
+                                      price, av) + reg_ys
             if fallback is not None:
                 ys = ys + (jnp.broadcast_to(fb, n_o.shape),
                            jnp.broadcast_to(err, n_o.shape))
-        new_carry = (z, n_prev, cost, done, T, plans)
-        if fallback is not None:
-            new_carry = new_carry + (err,)
-        return new_carry, ys
+        return ((z, n_prev, cost, done, T, plans), reg, fb_c), ys
 
     init = (
         jnp.zeros((p,), jnp.float32), jnp.zeros((p,), jnp.int32),
@@ -592,16 +656,32 @@ def _simulate_lanes_ahap(omega, v, sigma, rho, j: JobArrays, tput,
         jnp.zeros((p,), jnp.float32),
         jnp.zeros((p, VMAX, W1MAX, 2), jnp.float32),
     )
-    xs = (prices, av_i, pr, thr_s, z_exp_end, eff_slots, ts)
+    reg0 = reg_xs = fb0 = fb_xs = ()
+    if regional:
+        # free initial placement: each lane starts in its best region
+        reg0 = (jnp.argmin(sc[0], axis=-1).astype(jnp.int32),
+                jnp.zeros((p,), jnp.int32))
+        reg_xs = (sc,)
     if fallback is not None:
-        init = init + (jnp.float32(0.0),)
-        xs = xs + (prev1, prev_av)
-    (z, _, cost, done, T, *_rest), ys = jax.lax.scan(step, init, xs)
-    out = _finalize(jcfg, j, tput, z, cost, done, T,
+        fb0 = (jnp.zeros((p,), jnp.float32) if regional
+               else jnp.float32(0.0),)
+        fb_xs = (prev1, prev_av)
+    ((z, _, cost, done, T, _), reg_end, _), ys = jax.lax.scan(
+        step, (init, reg0, fb0),
+        (*market, pr, thr_s, z_exp_end, eff_slots, reg_xs, ts, fb_xs),
+    )
+    n_hist = 4 if regional else 2
+    j_fin, jcfg_fin = (_occupied(j, jcfg, p_od, reg_end[0]) if regional
+                       else (j, jcfg))
+    out = _finalize(jcfg_fin, j_fin, tput, z, cost, done, T,
                     jnp.swapaxes(ys[0], 0, 1), jnp.swapaxes(ys[1], 0, 1))
+    if regional:
+        out["region"] = jnp.swapaxes(ys[2], 0, 1)
+        out["migrations"] = ys[3].astype(jnp.int32).sum(axis=0)
     if collect:
-        keys = _TEL_SLOTS + (_TEL_FALLBACK if fallback is not None else ())
-        for key, hist in zip(keys, ys[2:]):
+        keys = (_TEL_SLOTS + (_TEL_REGION if regional else ())
+                + (_TEL_FALLBACK if fallback is not None else ()))
+        for key, hist in zip(keys, ys[n_hist:]):
             out[key] = jnp.swapaxes(hist, 0, 1)
     return out
 
@@ -645,56 +725,88 @@ def _simulate_one_ahap(omega, v, sigma, rho, j: JobArrays, tput,
 
 
 def _simulate_one_cheap(kind, sigma, cfrac, j: JobArrays, tput, prices, avail,
-                        collect: bool = False, fallback=None):
+                        collect: bool = False, fallback=None, rsel=None,
+                        rmargin=None, scores=None, delta_mig=None, p_od=None):
     """Non-AHAP lane (AHANP/OD/MSU/UP/RAND_DEADLINE): no forecasts, no
-    window DP — the whole step is a handful of VPU ops. ``collect``
-    (static) appends the ``_TEL_SLOTS`` series to the scan ys. Cheap lanes
-    consume no predictions, so ``fallback`` never changes their decisions;
-    it only (with collect) appends all-zero ``_TEL_FALLBACK`` placeholder
-    series so the merged pool result keeps one uniform key set."""
-    dmax = prices.shape[0]
+    window DP — the whole step is a handful of VPU ops, ``kind`` picking
+    among :func:`_cheap_rules`. ``collect`` (static) appends the
+    ``_TEL_SLOTS`` series to the scan ys. Cheap lanes consume no
+    predictions, so ``fallback`` never changes their decisions; it only
+    (with collect) appends all-zero ``_TEL_FALLBACK`` placeholder series so
+    the merged pool result keeps one uniform key set.
+
+    Region-axis rows (prices/avail (R, dmax)) select a region each slot as
+    :func:`_simulate_lanes_ahap` does, from ``scores`` — the
+    (dmax, N_RSEL, R) tensor of :func:`_region_scores`, shared across the
+    cheap lanes of one job — and add the same ``region``/``migrations``
+    leaves and ``_TEL_REGION`` series; ``p_od`` as there."""
+    regional = prices.ndim == 2
+    dmax = prices.shape[-1]
     jcfg = _job_cfg(j)
+    if regional:
+        av_i = avail.astype(jnp.int32)
+        sc = scores[:, rsel]                              # (dmax, R)
+        cur0 = jnp.argmin(sc[0]).astype(jnp.int32)
 
     def step(carry, xs):
-        z, n_prev, cost, done, T, prev_avail = carry
-        price, av, t = xs
-        an_o, an_s = _ahanp_rule(j, sigma, z, t, price, av, n_prev, prev_avail)
-        od_o, od_s = _od_rule(j, tput, z, t, price, av)
-        ms_o, ms_s = _msu_rule(j, tput, z, t, price, av)
-        up_o, up_s = _up_rule(j, tput, z, t, price, av)
-        rd_o, rd_s = _rand_rule(j, tput, cfrac, z, t, price, av)
-        n_o = jnp.select(
-            [kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [an_o, od_o, ms_o, up_o, rd_o],
+        (z, n_prev, cost, done, T, prev_avail), reg = carry
+        price, av, reg_xs, t = xs
+        j_t = j
+        reg_ys = ()
+        if regional:
+            (cur, mig_left), (sc_t,) = reg, reg_xs
+            cur, mig_left, migrating, switch = _region_step(
+                cur, mig_left, sc_t, rmargin, delta_mig,
+                done | (t >= j.deadline),
+            )
+            price, av = price[cur], av[cur]
+            j_t, _ = _occupied(j, jcfg, p_od, cur)
+            reg, reg_ys = (cur, mig_left), (cur, switch)
+        conds, cand_o, cand_s = _cheap_rules(
+            kind, j_t, tput, sigma, cfrac, z, t, price, av, n_prev,
+            prev_avail,
         )
-        n_s = jnp.select(
-            [kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [an_s, od_s, ms_s, up_s, rd_s],
-        )
+        n_o = jnp.select(conds(), cand_o)
+        n_s = jnp.select(conds(), cand_s)
+        if regional:
+            n_o = jnp.where(migrating, 0, n_o)
+            n_s = jnp.where(migrating, 0, n_s)
         n_prev0 = n_prev
         z, n_prev, cost, done, T, n_o, n_s, active = _execute(
-            j, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
+            j_t, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
         )
         prev_avail = jnp.where(active, av, prev_avail)
-        ys = (n_o, n_s)
+        ys = (n_o, n_s) + reg_ys
         if collect:
-            ys = ys + _slot_telemetry(j, n_prev0, z, n_o, n_s, active,
-                                      price, av)
+            ys = ys + _slot_telemetry(j_t, n_prev0, z, n_o, n_s, active,
+                                      price, av) + reg_ys
             if fallback is not None:
                 ys = ys + (jnp.bool_(False), jnp.float32(0.0))
-        return (z, n_prev, cost, done, T, prev_avail), ys
+        return ((z, n_prev, cost, done, T, prev_avail), reg), ys
 
     init = (
         jnp.float32(0.0), jnp.int32(0), jnp.float32(0.0),
-        jnp.bool_(False), jnp.float32(0.0), avail[0].astype(jnp.int32),
+        jnp.bool_(False), jnp.float32(0.0),
     )
-    (z, _, cost, done, T, _), ys = jax.lax.scan(
-        step, init, (prices, avail.astype(jnp.int32), jnp.arange(dmax))
-    )
-    out = _finalize(jcfg, j, tput, z, cost, done, T, ys[0], ys[1])
+    if regional:
+        init = (init + (av_i[cur0, 0],), (cur0, jnp.int32(0)))
+        xs = (jnp.swapaxes(prices, 0, 1), jnp.swapaxes(av_i, 0, 1), (sc,),
+              jnp.arange(dmax))
+    else:
+        init = (init + (avail[0].astype(jnp.int32),), ())
+        xs = (prices, avail.astype(jnp.int32), (), jnp.arange(dmax))
+    ((z, _, cost, done, T, _), reg_end), ys = jax.lax.scan(step, init, xs)
+    n_hist = 4 if regional else 2
+    j_fin, jcfg_fin = (_occupied(j, jcfg, p_od, reg_end[0]) if regional
+                       else (j, jcfg))
+    out = _finalize(jcfg_fin, j_fin, tput, z, cost, done, T, ys[0], ys[1])
+    if regional:
+        out["region"] = ys[2]
+        out["migrations"] = ys[3].astype(jnp.int32).sum()
     if collect:
-        keys = _TEL_SLOTS + (_TEL_FALLBACK if fallback is not None else ())
-        for key, hist in zip(keys, ys[2:]):
+        keys = (_TEL_SLOTS + (_TEL_REGION if regional else ())
+                + (_TEL_FALLBACK if fallback is not None else ()))
+        for key, hist in zip(keys, ys[n_hist:]):
             out[key] = hist
     return out
 
@@ -703,50 +815,40 @@ def _simulate_one_cheap(kind, sigma, cfrac, j: JobArrays, tput, prices, avail,
 # Pool entry points: partition by kind, scatter back to pool order
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit,
-                   static_argnames=("tput", "backend", "collect", "fallback"))
-def _pool_ahap(omega, v, sigma, rho, j: JobArrays, tput, prices, avail, pred,
-               backend: str, collect: bool = False, fallback=None):
-    return _simulate_lanes_ahap(
-        omega, v, sigma, rho, j, tput, prices, avail, pred, backend,
-        collect=collect, fallback=fallback,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("tput", "collect", "fallback"))
-def _pool_cheap(kind, sigma, cfrac, j: JobArrays, tput, prices, avail,
-                collect: bool = False, fallback=None):
-    fn = lambda k, s, c: _simulate_one_cheap(k, s, c, j, tput, prices, avail,
-                                             collect=collect,
-                                             fallback=fallback)
-    return jax.vmap(fn)(kind, sigma, cfrac)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("tput", "backend", "collect", "fallback"))
+@functools.partial(jax.jit, static_argnames=("tput", "backend", "collect",
+                                             "fallback", "delta_mig"))
 def _pool_jobs_ahap(omega, v, sigma, rho, jobs: JobArrays, tput,
                     prices, avail, pred, backend: str, collect: bool = False,
-                    fallback=None):
+                    fallback=None, rsel=None, rmargin=None, delta_mig=None,
+                    p_od=None):
     def per_job(job_row, pr_, av_, pm_):
         return _simulate_lanes_ahap(
             omega, v, sigma, rho, job_row, tput, pr_, av_, pm_, backend,
-            collect=collect, fallback=fallback,
+            collect=collect, fallback=fallback, rsel=rsel, rmargin=rmargin,
+            delta_mig=delta_mig, p_od=p_od,
         )
 
     return jax.vmap(per_job)(jobs, prices, avail, pred)
 
 
-@functools.partial(jax.jit, static_argnames=("tput", "collect", "fallback"))
+@functools.partial(jax.jit, static_argnames=("tput", "collect", "fallback",
+                                             "delta_mig"))
 def _pool_jobs_cheap(kind, sigma, cfrac, jobs: JobArrays, tput, prices, avail,
-                     collect: bool = False, fallback=None):
-    def per_job(job_row, pr_, av_):
-        fn = lambda k, s, c: _simulate_one_cheap(
+                     collect: bool = False, fallback=None, rsel=None,
+                     rmargin=None, pred=None, delta_mig=None, p_od=None):
+    # cheap lanes take no forecasts: ``pred`` (region-axis runs only) feeds
+    # the region scores, shared by every cheap lane of a job
+    def per_job(job_row, pr_, av_, pm_):
+        scores = (None if pm_ is None else
+                  _region_scores(job_row, pr_, av_.astype(jnp.int32), pm_))
+        fn = lambda k, s, c, rs, rm: _simulate_one_cheap(
             k, s, c, job_row, tput, pr_, av_, collect=collect,
-            fallback=fallback,
+            fallback=fallback, rsel=rs, rmargin=rm, scores=scores,
+            delta_mig=delta_mig, p_od=p_od,
         )
-        return jax.vmap(fn)(kind, sigma, cfrac)
+        return jax.vmap(fn)(kind, sigma, cfrac, rsel, rmargin)
 
-    return jax.vmap(per_job)(jobs, prices, avail)
+    return jax.vmap(per_job)(jobs, prices, avail, pred)
 
 
 def _partition(pool_arrays: dict):
@@ -801,19 +903,19 @@ def _device(x):
     return jnp.asarray(x)
 
 
-def _partition_lane_args(pool_arrays: dict, with_regions: bool):
+def _partition_lane_args(pool_arrays: dict, regional: bool):
     """(ahap_idx, other_idx, ahap_args, cheap_args): the per-partition lane
     parameter tuples (numpy) shared by the local and sharded drivers —
     slicing lives in ONE place so a new pool-array slot cannot be wired into
-    one driver and silently zero-defaulted in the other. With
-    ``with_regions`` each tuple additionally carries the partition's
-    (rsel, rmargin) region-strategy slices (defaulting to stay-put lanes
-    when the pool encoding predates the region slots)."""
+    one driver and silently zero-defaulted in the other. For a ``regional``
+    run each tuple additionally carries the partition's (rsel, rmargin)
+    region-strategy slices (defaulting to stay-put lanes when the pool
+    encoding predates the region slots)."""
     ahap_idx, other_idx, rho, cfrac = _partition(pool_arrays)
     arr = lambda k: np.asarray(pool_arrays[k])
     n = len(arr("kind"))
     extras = lambda idx: ()
-    if with_regions:
+    if regional:
         rsel = pool_arrays.get("rsel")
         rsel = (np.zeros(n, np.int32) if rsel is None
                 else np.asarray(rsel, np.int32))
@@ -828,82 +930,132 @@ def _partition_lane_args(pool_arrays: dict, with_regions: bool):
     return ahap_idx, other_idx, ahap_args, cheap_args
 
 
-def _run_partitioned(pool_arrays, ahap_call, cheap_call, axis: int,
-                     with_regions: bool = False):
-    """Shared partition -> dispatch -> scatter-back driver for every
-    single-device pool entry point (axis is the policy-lane axis of the
-    result leaves; lane slicing in :func:`_partition_lane_args`)."""
+def _program_args(ahap: bool, lanes, jobs, tput, prices, avail, pred,
+                  backend: str, collect: bool, fallback, region):
+    """(program, positional args) of one kind partition, for both drivers:
+    ``lanes`` as :func:`_partition_lane_args` slices them, ``region`` None
+    for a flat market or ``(delta_mig, p_od)``. A flat call passes nothing
+    the flat program does not take."""
+    if ahap:
+        return _pool_jobs_ahap, (
+            *lanes[:4], jobs, tput, prices, avail, pred, backend, collect,
+            fallback, *lanes[4:], *(region or ()))
+    return _pool_jobs_cheap, (
+        *lanes[:3], jobs, tput, prices, avail, collect, fallback,
+        *lanes[3:], *((pred, *region) if region else ()))
+
+
+def _run_partitioned(pool_arrays, jobs, tput, prices, avail, pred,
+                     backend: str, collect: bool, fallback, region):
+    """Partition -> dispatch -> scatter-back driver of the single-device
+    pool (lane slicing in :func:`_partition_lane_args`)."""
     with TraceAnnotation("sched.pool.lanes"):
         ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
-            pool_arrays, with_regions
+            pool_arrays, region is not None
         )
 
-    def run(span, call, lane_args):
+    def run(ahap: bool, lane_args):
         # each partition's lanes go over just before its program, so the
         # AHAP program starts before the cheap lanes are sent
         with TraceAnnotation("sched.pool.lanes"):
             counters.h2d(lane_args)
             lane_args = tuple(jnp.asarray(a) for a in lane_args)
-        with TraceAnnotation(span):
-            return call(*lane_args)
+        program, args = _program_args(ahap, lane_args, jobs, tput, prices,
+                                      avail, pred, backend, collect,
+                                      fallback, region)
+        with TraceAnnotation("sched.pool.ahap" if ahap else
+                             "sched.pool.cheap"):
+            return counters.launch(program, *args)
 
     parts, idxs = [], []
     if ahap_idx.size:
-        parts.append(run("sched.pool.ahap", ahap_call, ahap_args))
+        parts.append(run(True, ahap_args))
         idxs.append(ahap_idx)
     if other_idx.size:
-        parts.append(run("sched.pool.cheap", cheap_call, cheap_args))
+        parts.append(run(False, cheap_args))
         idxs.append(other_idx)
-    return _scatter_merge(parts, idxs, axis=axis)
+    return _scatter_merge(parts, idxs, axis=1)
+
+
+def _as_p_od(p_od, n_regions: int):
+    """Normalize a per-region on-demand price multiplier: None passes
+    through (the flat-od program is traced unchanged), a scalar broadcasts
+    to (R,), an (R,) array is taken as-is."""
+    if p_od is None:
+        return None
+    x = p_od if isinstance(p_od, jax.Array) else np.asarray(p_od)
+    counters.h2d(x)
+    # a program each for the float32 conversion, the reshape and the
+    # broadcast, where they change anything
+    counters.add("launches", int(x.dtype != np.float32) + int(x.ndim != 1)
+                 + int(x.size != n_regions))
+    return jnp.broadcast_to(
+        jnp.asarray(p_od, jnp.float32).reshape(-1), (n_regions,)
+    )
+
+
+def _region(prices, delta_mig, p_od):
+    """The region operands of a pool call: None for flat (J, d_max) market
+    rows, ``(delta_mig, p_od)`` for (J, R, d_max) rows — which must bring
+    their ``delta_mig``: a default here would silently override the cost a
+    RegionalMarket was built with."""
+    if np.ndim(prices) != 3:
+        if delta_mig is not None or p_od is not None:
+            raise ValueError("delta_mig and p_od apply to region-axis "
+                             "(J, R, d_max) market rows only")
+        return None
+    if delta_mig is None:
+        raise ValueError("region-axis market rows need delta_mig (the "
+                         "market's migration cost in slots)")
+    return int(delta_mig), _as_p_od(p_od, np.shape(prices)[1])
 
 
 def simulate_pool(pool_arrays: dict, j: JobArrays, tput: ThroughputConfig,
                   prices, avail, pred, backend: str = "xla",
                   collect: bool = False, fallback=None):
-    """Kind-partitioned pool simulation. pool_arrays from specs_to_arrays;
-    results are returned in the original pool order (same leaves/shapes as
-    the seed monolithic path, pinned against simulator.simulate).
-    ``collect=True`` adds the (P, T) ``tel_*`` flight-recorder series
-    (repro.obs) to the result; False is the bitwise-pinned default.
-    ``fallback`` (static repro.chaos.FallbackConfig) arms the AHAP lanes'
-    online prediction-failure fallback; None is the bitwise-pinned
-    default."""
-    return _run_partitioned(
-        pool_arrays,
-        lambda w, v, s, r: counters.launch(
-            _pool_ahap, w, v, s, r, j, tput, prices, avail, pred, backend,
-            collect, fallback,
-        ),
-        lambda k, s, c: counters.launch(
-            _pool_cheap, k, s, c, j, tput, prices, avail, collect, fallback,
-        ),
-        axis=0,
-    )
+    """One job's :func:`simulate_pool_jobs`: ``j`` holds scalar leaves,
+    prices/avail are (d_max,), pred (d_max, W1MAX, 2); results are (P, ...)
+    in the original pool order (pinned against simulator.simulate)."""
+    rows = [jnp.asarray(x)[None] for x in (*j, prices, avail, pred)]
+    counters.h2d((j, prices, avail, pred))
+    counters.add("launches", len(rows))          # a reshape per operand
+    out = simulate_pool_jobs(pool_arrays, JobArrays(*rows[:-3]), tput,
+                             *rows[-3:], backend, collect, fallback)
+    counters.add("launches", len(out))           # a slice per result leaf
+    return {k: v[0] for k, v in out.items()}
 
 
 def simulate_pool_jobs(pool_arrays: dict, jobs: JobArrays, tput: ThroughputConfig,
                        prices, avail, pred, backend: str = "xla",
-                       collect: bool = False, fallback=None):
+                       collect: bool = False, fallback=None, *,
+                       delta_mig=None, p_od=None):
     """Double vmap: jobs (leading axis) x policy pool -> dict of (J, P, ...).
 
     ``jobs`` leaves are stacked (J,) arrays; prices/avail: (J, d_max);
-    pred: (J, d_max, W1MAX, 2). One XLA call per kind-partition simulates
-    the paper's whole Fig. 9/10 workload. ``collect=True`` adds the
-    (J, P, T) ``tel_*`` flight-recorder series (repro.obs); ``fallback``
-    (static repro.chaos.FallbackConfig) arms the AHAP lanes' online
-    prediction-failure fallback (None — the default — is bitwise-pinned
-    to the shipped program)."""
+    pred: (J, d_max, W1MAX, 2) (see :func:`prepare_inputs`). One XLA call
+    per kind-partition simulates the paper's whole Fig. 9/10 workload.
+    ``collect=True`` adds the (J, P, T) ``tel_*`` flight-recorder series
+    (repro.obs); ``fallback`` (static repro.chaos.FallbackConfig) arms the
+    AHAP lanes' online prediction-failure fallback (None — the default —
+    is bitwise-pinned to the shipped program).
+
+    **Regions** — (J, R, d_max) prices/avail and (J, R, d_max, W1MAX, 2)
+    pred run the region-aware lanes over an R-region market. ``delta_mig``
+    is then required: the checkpoint-transfer cost in lost slots (pass
+    ``market.delta_mig``). Lanes read their region-selection strategy from
+    pool_arrays' ``rsel``/``rmargin`` slots (policy_pool.region_pool;
+    absent keys mean every lane stays put). The result gains ``region``
+    (the lane's region each slot) and ``migrations`` (completed switches);
+    collect adds ``tel_region``/``tel_migration`` (slot sums reconcile
+    against ``migrations`` in obs.ledger.migration_reconciliation), and
+    the fallback monitor runs per lane. ``p_od`` (scalar or (R,)) scales
+    the on-demand price by occupied region (``market.p_od``; multipliers
+    of the job's flat ``on_demand_price``). With R == 1 the shared leaves
+    are bitwise-identical to the flat market's. ``delta_mig`` or ``p_od``
+    with flat rows raises ``ValueError``."""
     return _run_partitioned(
-        pool_arrays,
-        lambda w, v, s, r: counters.launch(
-            _pool_jobs_ahap, w, v, s, r, jobs, tput, prices, avail, pred,
-            backend, collect, fallback,
-        ),
-        lambda k, s, c: counters.launch(
-            _pool_jobs_cheap, k, s, c, jobs, tput, prices, avail, collect,
-            fallback,
-        ),
-        axis=1,
+        pool_arrays, jobs, tput, prices, avail, pred, backend, collect,
+        fallback, _region(prices, delta_mig, p_od),
     )
 
 
@@ -920,61 +1072,34 @@ def _pad_leading(x, pad: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_pool_call(mesh, tput, backend: str, delta_mig: int,
-                       with_regions: bool, ahap: bool, lspec, jspec, ospec,
-                       collect: bool = False, fallback=None,
-                       has_p_od: bool = False):
+def _sharded_pool_call(mesh, tput, backend: str, delta_mig, ahap: bool,
+                       lspec, jspec, ospec, collect: bool = False,
+                       fallback=None, has_p_od: bool = False):
     """jit(shard_map)-wrapped runner for one kind partition, cached on the
-    static configuration (``collect``, ``fallback`` and ``has_p_od`` are
-    part of the key: the telemetry, degradation and per-region-od programs
-    are different lowerings; ``has_p_od`` adds a replicated (R,) operand).
+    static configuration (``collect``, ``fallback``, ``delta_mig`` and
+    ``has_p_od`` are part of the key: the telemetry, degradation, region
+    and per-region-od programs are different lowerings; a ``delta_mig``
+    (None on a flat market) adds the (rsel, rmargin) lane operands,
+    ``has_p_od`` a replicated (R,) one).
     The cache is what keeps the sharded path's per-call cost at dispatch
     level: a fresh shard_map closure per call would retrace (and re-lower)
     the whole pool program every invocation — the prime mover of the old
-    1000-job sharded-scale regression. Each runner has a name of its own
-    (``jit_sharded_pool_<kind>[_regions[_pod]]`` in a device trace)."""
-    if ahap and with_regions:
-        if has_p_od:
-            def local(w, v_, s, r, rs, rm, jb, pr_, av_, pm_, po):
-                return _pool_jobs_ahap_regions(
-                    w, v_, s, r, rs, rm, jb, tput, pr_, av_, pm_, backend,
-                    delta_mig, collect, fallback, po,
-                )
-        else:
-            def local(w, v_, s, r, rs, rm, jb, pr_, av_, pm_):
-                return _pool_jobs_ahap_regions(
-                    w, v_, s, r, rs, rm, jb, tput, pr_, av_, pm_, backend,
-                    delta_mig, collect, fallback,
-                )
-        n_lane = 6
-    elif ahap:
-        def local(w, v_, s, r, jb, pr_, av_, pm_):
-            return _pool_jobs_ahap(w, v_, s, r, jb, tput, pr_, av_, pm_,
-                                   backend, collect, fallback)
-        n_lane = 4
-    elif with_regions:
-        if has_p_od:
-            def local(k, s, c, rs, rm, jb, pr_, av_, pm_, po):
-                return _pool_jobs_cheap_regions(
-                    k, s, c, rs, rm, jb, tput, pr_, av_, pm_, delta_mig,
-                    collect, fallback, po,
-                )
-        else:
-            def local(k, s, c, rs, rm, jb, pr_, av_, pm_):
-                return _pool_jobs_cheap_regions(
-                    k, s, c, rs, rm, jb, tput, pr_, av_, pm_, delta_mig,
-                    collect, fallback,
-                )
-        n_lane = 5
-    else:
-        # pm_ rides along unused: cheap lanes take no forecasts
-        def local(k, s, c, jb, pr_, av_, pm_):
-            return _pool_jobs_cheap(k, s, c, jb, tput, pr_, av_, collect,
-                                    fallback)
-        n_lane = 3
-    local.__name__ = ("sharded_pool_" + ("ahap" if ahap else "cheap")
-                      + ("_regions" if with_regions else "")
-                      + ("_pod" if has_p_od else ""))
+    1000-job sharded-scale regression. The two runners are named apart
+    (``jit_sharded_pool_<kind>`` in a device trace)."""
+    regional = delta_mig is not None
+    n_lane = (4 if ahap else 3) + (2 if regional else 0)
+
+    # flat cheap lanes take no forecasts: pm_ rides along unused
+    def local(*ops):
+        lanes, (jb, pr_, av_, pm_) = ops[:n_lane], ops[n_lane:n_lane + 4]
+        region = None
+        if regional:
+            region = (delta_mig, ops[n_lane + 4] if has_p_od else None)
+        program, args = _program_args(ahap, lanes, jb, tput, pr_, av_, pm_,
+                                      backend, collect, fallback, region)
+        return program(*args)
+
+    local.__name__ = "sharded_pool_" + ("ahap" if ahap else "cheap")
     from jax.sharding import PartitionSpec
 
     # the tiny (R,) od-multiplier vector is replicated to every device
@@ -987,9 +1112,8 @@ def _sharded_pool_call(mesh, tput, backend: str, delta_mig: int,
 
 
 def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
-                             backend: str, mesh, *, with_regions: bool = False,
-                             delta_mig: int = 0, collect: bool = False,
-                             fallback=None, p_od=None):
+                             backend: str, mesh, *, collect: bool = False,
+                             fallback=None, region=None):
     """Sharded twin of :func:`_run_partitioned`: partition by kind on the
     host, then lay each partition's (jobs x lanes) grid over ``mesh``.
 
@@ -1000,11 +1124,13 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
     Both axes pad to divisibility by repeating the last entry; padding is
     dropped before the scatter-merge back to pool order. Market data
     (prices/avail/pred) is sharded over jobs and replicated only across the
-    lane axis, where every lane shard genuinely needs all of it."""
+    lane axis, where every lane shard genuinely needs all of it; a region
+    axis rides whole inside it. ``region`` as in :func:`_program_args`."""
     from repro import sharding as shardlib
     from repro.launch.mesh import pool_mesh_job_axes
 
     jobs_axes, n_jobs_dev, n_lane_dev = pool_mesh_job_axes(mesh)
+    delta_mig, p_od = region or (None, None)
 
     n_jobs = int(np.shape(jobs.workload)[0])
     pad_j = (-n_jobs) % n_jobs_dev
@@ -1021,7 +1147,7 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
                 _pad_leading(x, pad_j) for x in (prices, avail, pred)
             )
         ahap_idx, other_idx, ahap_args, cheap_args = _partition_lane_args(
-            pool_arrays, with_regions
+            pool_arrays, region is not None
         )
         pr_j, av_j, pm_j = (_device(x) for x in (prices, avail, pred))
         pod_args = () if p_od is None else (_device(p_od),)
@@ -1036,8 +1162,8 @@ def _run_partitioned_sharded(pool_arrays, jobs, tput, prices, avail, pred,
             ("jobs", "lanes"), (n_jobs + pad_j, p_l + pad_l), mesh, rules
         )
         call = _sharded_pool_call(
-            mesh, tput, backend, int(delta_mig), with_regions, ahap,
-            lspec, jspec, ospec, collect, fallback, p_od is not None,
+            mesh, tput, backend, delta_mig, ahap, lspec, jspec, ospec,
+            collect, fallback, p_od is not None,
         )
         with TraceAnnotation("sched.pool.ahap" if ahap else
                              "sched.pool.cheap"):
@@ -1072,6 +1198,9 @@ def simulate_pool_jobs_sharded(
     mesh=None,
     collect: bool = False,
     fallback=None,
+    *,
+    delta_mig=None,
+    p_od=None,
 ):
     """Device-sharded :func:`simulate_pool_jobs`: the (jobs x lanes) grid is
     laid over ``mesh`` (default: repro.launch.mesh.make_pool_mesh over every
@@ -1083,19 +1212,23 @@ def simulate_pool_jobs_sharded(
     sharding, so each device runs a uniform DP-heavy-AHAP or cheap lane
     slice of its job shard (load balance by construction). Jobs and lanes
     that do not divide their mesh axis are padded by repeating the last
-    entry; padding is dropped from the result.
+    entry; padding is dropped from the result. Region-axis inputs
+    (``delta_mig``, ``p_od``) as in :func:`simulate_pool_jobs`: the small
+    region axis rides whole per device inside the (J, R, T) market tensors
+    and ``p_od``, when set, is replicated to every device.
 
     Per-(job, lane) cells are independent and every op is elementwise over
     both axes, so the result is BITWISE-equal to ``simulate_pool_jobs``
     (pinned in tests/test_sharded_pool.py for the jobs, lanes and 2-D
-    layouts). With one visible device this falls through to
-    ``simulate_pool_jobs`` itself. ``collect=True`` adds the (J, P, T)
-    ``tel_*`` flight-recorder series (repro.obs); telemetry shards like
-    the allocation histories, so sharded collect runs stay bitwise-equal
-    to unsharded ones. ``fallback`` (static repro.chaos.FallbackConfig)
-    arms the AHAP lanes' online prediction-failure fallback — the monitor
-    is per-(job, lane)-cell local, so sharded fallback runs stay
-    bitwise-equal to unsharded ones too.
+    layouts, flat and regional). With one visible device this falls
+    through to ``simulate_pool_jobs`` itself. ``collect=True`` adds the
+    (J, P, T) ``tel_*`` flight-recorder series (repro.obs); telemetry
+    shards like the allocation histories, so sharded collect runs stay
+    bitwise-equal to unsharded ones. ``fallback`` (static
+    repro.chaos.FallbackConfig) arms the AHAP lanes' online
+    prediction-failure fallback — the monitor is per-(job, lane)-cell
+    local, so sharded fallback runs stay bitwise-equal to unsharded ones
+    too.
     """
     from repro.launch.mesh import make_pool_mesh
 
@@ -1104,455 +1237,13 @@ def simulate_pool_jobs_sharded(
     if int(np.prod(mesh.devices.shape)) == 1:
         return simulate_pool_jobs(
             pool_arrays, jobs, tput, prices, avail, pred, backend=backend,
-            collect=collect, fallback=fallback,
-        )
-    return _run_partitioned_sharded(
-        pool_arrays, jobs, tput, prices, avail, pred, backend, mesh,
-        collect=collect, fallback=fallback,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Multi-region lanes (BEYOND-PAPER, SkyNomad arXiv:2601.06520)
-# ---------------------------------------------------------------------------
-#
-# ``simulate_pool_regions`` layers per-slot region selection over the kind-
-# partitioned scans: every lane carries a current-region state, scores all
-# regions each slot (vectorized, from data precomputed outside the scan),
-# switches with a hysteresis margin, pays ``delta_mig`` zero-allocation
-# slots per switch (checkpoint transfer), and feeds the selected region's
-# (price, avail, forecast) into the unmodified decision rules. With R == 1
-# the selector can never leave region 0 and every migration branch is a
-# no-op ``where`` passthrough, so results are BITWISE-identical to
-# ``simulate_pool_jobs`` (pinned in tests/test_region_sim.py).
-
-# the pred_horizon score averages a fixed-width forecast window; the python
-# reference (policies.RegionSelector.scores) pads/trims to the same width
-assert RSEL_PRED_WINDOW == W1MAX
-
-
-def _region_scores(j: JobArrays, prices, avail, pred):
-    """(dmax, 4, R) lower-better scores from (R, dmax) market data and
-    (R, dmax, W1MAX, 2) forecasts — the jnp twin of
-    policies.RegionSelector.scores, all four RSEL_* strategies at once
-    (lanes gather their row by ``rsel``). Scan-invariant: computed once per
-    (job, trace)."""
-    nmin_f = j.n_min.astype(jnp.float32)
-    dead = (avail < j.n_min).astype(jnp.float32)
-    price_sc = prices + RSEL_BIG * dead                   # (R, dmax)
-    avail_sc = -avail.astype(jnp.float32)
-    pdead = (pred[..., 1] < nmin_f).astype(jnp.float32)
-    pred_sc = jnp.mean(pred[..., 0] + RSEL_BIG * pdead, axis=-1)
-    sc = jnp.stack(
-        [jnp.zeros_like(price_sc), price_sc, avail_sc, pred_sc]
-    )                                                     # (4, R, dmax)
-    return jnp.transpose(sc, (2, 0, 1))
-
-
-def _region_step(cur, mig_left, sc_row, rmargin, delta_mig: int, inactive):
-    """One slot of region selection: argmin with hysteresis + migration
-    bookkeeping. Batched over lanes (cur/mig_left (P,), sc_row (P, R)) or
-    scalar (cur/mig_left scalars, sc_row (R,)). Returns
-    (cur, mig_left, migrating, switched); ``migrating`` slots execute with
-    zero instances (the checkpoint is in transit). ``inactive`` lanes
-    (completed, or past their deadline in a heterogeneous-deadline batch)
-    never switch — the reference loop has stopped by then, so late score
-    flips must not move (or count against) such a job."""
-    best = jnp.argmin(sc_row, axis=-1).astype(jnp.int32)
-    cur_sc = jnp.take_along_axis(sc_row, cur[..., None], -1)[..., 0]
-    best_sc = jnp.take_along_axis(sc_row, best[..., None], -1)[..., 0]
-    switch = ((best != cur) & (best_sc + rmargin < cur_sc)
-              & (mig_left == 0) & ~inactive)
-    cur = jnp.where(switch, best, cur)
-    mig_left = jnp.where(
-        switch, jnp.int32(delta_mig), jnp.maximum(mig_left - 1, 0)
-    )
-    return cur, mig_left, mig_left > 0, switch
-
-
-def _simulate_lanes_ahap_regions(omega, v, sigma, rho, rsel, rmargin,
-                                 j: JobArrays, tput, prices, avail, pred,
-                                 backend: str, delta_mig: int,
-                                 collect: bool = False, fallback=None,
-                                 p_od=None):
-    """Region-aware :func:`_simulate_lanes_ahap`: prices/avail are (R, dmax),
-    pred is (R, dmax, W1MAX, 2). The AHAP scaffolding is precomputed per
-    (lane, region, slot); each scan slot selects a region per lane and
-    gathers that region's row before the unchanged lane-batched CHC rule.
-
-    ``collect`` (static) appends the ``_TEL_SLOTS`` series plus the
-    ``_TEL_REGION`` pair (per-slot region occupancy + switch events) to the
-    scan ys; False traces the identical shipped program. ``fallback``
-    (static FallbackConfig, or None) arms the prediction-health monitor of
-    :func:`_simulate_lanes_ahap`, except the error EWMA is per-lane (P,) —
-    lanes occupy different regions, so each lane scores the 1-step-ahead
-    forecast of ITS region against that region's realized market. ``p_od``
-    (traced (R,) array, or None) scales the job's on-demand price per
-    region (multipliers; termination billing uses the lane's final region);
-    None traces the flat-od program unchanged."""
-    dmax = prices.shape[1]
-    p = omega.shape[0]
-    jcfg = _job_cfg(j)
-    ts = jnp.arange(dmax)
-    av_i = avail.astype(jnp.int32)
-    # per-region od price: thr_s thresholds see the (R, 1) effective price
-    # broadcast against the (R, W1MAX) forecast rows
-    j_pre = j if p_od is None else j._replace(p_o=j.p_o * p_od[:, None])
-    # slot-major from the start (see _simulate_lanes_ahap): the (R, dmax)
-    # raw forecast stack is transposed ONCE (small), then slots ride the
-    # outer vmap so the big per-(slot, lane, region) tensors are born in
-    # scan-xs layout — the old lane-major vmap built (P, R, dmax, ...)
-    # tensors and 5-D transposed copies of them at every scan boundary.
-    pred_sm = jnp.swapaxes(pred, 0, 1)           # (dmax, R, W1MAX, 2)
-    with jax.named_scope("ahap_precompute"):
-        pr, thr_s, z_exp_end, eff_slots = jax.vmap(
-            lambda t, pm: jax.vmap(
-                lambda w, s, r: _ahap_precompute(j_pre, w, s, r, t, pm)
-            )(omega, sigma, rho)
-        )(ts, pred_sm)
-    # pr (dmax, P, R, W1MAX, 2); thr_s (dmax, P, R, W1MAX); rest (dmax, P)
-    sc = _region_scores(j, prices, av_i, pred)[:, rsel]  # (dmax, P, R)
-    lane = jnp.arange(p)
-    if fallback is not None:
-        thr = jnp.float32(fallback.threshold)
-        prev1 = jnp.swapaxes(jax.vmap(_fallback_prev1)(pred), 0, 1)
-        prev_av = jnp.swapaxes(
-            jnp.concatenate([av_i[:, :1], av_i[:, :-1]], axis=1), 0, 1
-        )                                        # (dmax, R)
-
-    def step(carry, xs):
-        if fallback is not None:
-            z, n_prev, cost, done, T, plans, cur, mig_left, err = carry
-            (prices_t, avail_t, pr_t, thr_t, zee_t, eff_t, sc_t, t,
-             p1_t, pav_t) = xs
-        else:
-            z, n_prev, cost, done, T, plans, cur, mig_left = carry
-            prices_t, avail_t, pr_t, thr_t, zee_t, eff_t, sc_t, t = xs
-        cur, mig_left, migrating, switch = _region_step(
-            cur, mig_left, sc_t, rmargin, delta_mig,
-            done | (t >= j.deadline),
-        )
-        price = prices_t[cur]                    # (P,) per-lane region price
-        av = avail_t[cur]
-        j_t = j if p_od is None else j._replace(p_o=j.p_o * p_od[cur])
-        jcfg_t = jcfg if p_od is None else _job_cfg(j_t)
-        if fallback is not None:
-            p1_sel = p1_t[cur]                   # (P, 2) lane-region forecasts
-            err = _fallback_error(fallback, err, price, av,
-                                  (p1_sel[:, 0], p1_sel[:, 1]))
-            fb = err > thr
-        n_o, n_s, plans = _ahap_rule_batch(
-            jcfg_t, j_t, tput, v, backend, z, t, price, av, plans,
-            pr_t[lane, cur], thr_t[lane, cur], zee_t, eff_t,
-        )
-        if fallback is not None:
-            an_o, an_s = _ahanp_rule(j_t, sigma, z, t, price, av, n_prev,
-                                     pav_t[cur])
-            n_o = jnp.where(fb, an_o, n_o)
-            n_s = jnp.where(fb, an_s, n_s)
-        n_o = jnp.where(migrating, 0, n_o)
-        n_s = jnp.where(migrating, 0, n_s)
-        n_prev0 = n_prev
-        z, n_prev, cost, done, T, n_o, n_s, active = _execute(
-            j_t, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
-        )
-        ys = (n_o, n_s, cur, switch)
-        if collect:
-            ys = ys + _slot_telemetry(j_t, n_prev0, z, n_o, n_s, active,
-                                      price, av)
-            ys = ys + (cur, switch)
-            if fallback is not None:
-                ys = ys + (jnp.broadcast_to(fb, n_o.shape),
-                           jnp.broadcast_to(err, n_o.shape))
-        new_carry = (z, n_prev, cost, done, T, plans, cur, mig_left)
-        if fallback is not None:
-            new_carry = new_carry + (err,)
-        return new_carry, ys
-
-    init = (
-        jnp.zeros((p,), jnp.float32), jnp.zeros((p,), jnp.int32),
-        jnp.zeros((p,), jnp.float32), jnp.zeros((p,), jnp.bool_),
-        jnp.zeros((p,), jnp.float32),
-        jnp.zeros((p, VMAX, W1MAX, 2), jnp.float32),
-        jnp.argmin(sc[0], axis=-1).astype(jnp.int32),  # free initial placement
-        jnp.zeros((p,), jnp.int32),
-    )
-    xs = (jnp.swapaxes(prices, 0, 1), jnp.swapaxes(av_i, 0, 1),
-          pr, thr_s, z_exp_end, eff_slots, sc, ts)
-    if fallback is not None:
-        init = init + (jnp.zeros((p,), jnp.float32),)
-        xs = xs + (prev1, prev_av)
-    (z, _, cost, done, T, _, cur_end, *_rest), ys = jax.lax.scan(
-        step, init, xs
-    )
-    no_hist, ns_hist, cur_hist, sw_hist = ys[:4]
-    j_fin = j if p_od is None else j._replace(p_o=j.p_o * p_od[cur_end])
-    jcfg_fin = jcfg if p_od is None else _job_cfg(j_fin)
-    out = _finalize(jcfg_fin, j_fin, tput, z, cost, done, T,
-                    jnp.swapaxes(no_hist, 0, 1), jnp.swapaxes(ns_hist, 0, 1))
-    out["region"] = jnp.swapaxes(cur_hist, 0, 1)
-    out["migrations"] = sw_hist.astype(jnp.int32).sum(axis=0)
-    if collect:
-        keys = (_TEL_SLOTS + _TEL_REGION
-                + (_TEL_FALLBACK if fallback is not None else ()))
-        for key, hist in zip(keys, ys[4:]):
-            out[key] = jnp.swapaxes(hist, 0, 1)
-    return out
-
-
-def _simulate_one_cheap_regions(kind, sigma, cfrac, rsel, rmargin,
-                                j: JobArrays, tput, prices, avail, scores,
-                                delta_mig: int, collect: bool = False,
-                                fallback=None, p_od=None):
-    """Region-aware :func:`_simulate_one_cheap`: same DP-free rules, fed the
-    per-slot selected region's (price, avail). ``scores`` is the
-    (dmax, N_RSEL, R) tensor from :func:`_region_scores` (shared across the
-    cheap lanes of one job). ``collect`` appends the ``_TEL_SLOTS`` +
-    ``_TEL_REGION`` series; cheap lanes consume no predictions, so
-    ``fallback`` only (with collect) appends the all-zero ``_TEL_FALLBACK``
-    placeholders that keep the merged pool key set uniform. ``p_od``
-    ((R,) multipliers, or None) scales the on-demand price by the occupied
-    region, as in :func:`_simulate_lanes_ahap_regions`."""
-    dmax = prices.shape[1]
-    jcfg = _job_cfg(j)
-    av_i = avail.astype(jnp.int32)
-    sc = scores[:, rsel]                                  # (dmax, R)
-    cur0 = jnp.argmin(sc[0]).astype(jnp.int32)
-
-    def step(carry, xs):
-        z, n_prev, cost, done, T, prev_avail, cur, mig_left = carry
-        prices_t, avail_t, sc_t, t = xs
-        cur, mig_left, migrating, switch = _region_step(
-            cur, mig_left, sc_t, rmargin, delta_mig,
-            done | (t >= j.deadline),
-        )
-        price = prices_t[cur]
-        av = avail_t[cur]
-        j_t = j if p_od is None else j._replace(p_o=j.p_o * p_od[cur])
-        an_o, an_s = _ahanp_rule(j_t, sigma, z, t, price, av, n_prev,
-                                 prev_avail)
-        od_o, od_s = _od_rule(j_t, tput, z, t, price, av)
-        ms_o, ms_s = _msu_rule(j_t, tput, z, t, price, av)
-        up_o, up_s = _up_rule(j_t, tput, z, t, price, av)
-        rd_o, rd_s = _rand_rule(j_t, tput, cfrac, z, t, price, av)
-        n_o = jnp.select(
-            [kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [an_o, od_o, ms_o, up_o, rd_o],
-        )
-        n_s = jnp.select(
-            [kind == 1, kind == 2, kind == 3, kind == 4, kind == 5],
-            [an_s, od_s, ms_s, up_s, rd_s],
-        )
-        n_o = jnp.where(migrating, 0, n_o)
-        n_s = jnp.where(migrating, 0, n_s)
-        n_prev0 = n_prev
-        z, n_prev, cost, done, T, n_o, n_s, active = _execute(
-            j_t, tput, z, n_prev, cost, done, T, t, n_o, n_s, price, av
-        )
-        prev_avail = jnp.where(active, av, prev_avail)
-        ys = (n_o, n_s, cur, switch)
-        if collect:
-            ys = ys + _slot_telemetry(j_t, n_prev0, z, n_o, n_s, active,
-                                      price, av)
-            ys = ys + (cur, switch)
-            if fallback is not None:
-                ys = ys + (jnp.bool_(False), jnp.float32(0.0))
-        return ((z, n_prev, cost, done, T, prev_avail, cur, mig_left), ys)
-
-    init = (
-        jnp.float32(0.0), jnp.int32(0), jnp.float32(0.0),
-        jnp.bool_(False), jnp.float32(0.0), av_i[cur0, 0],
-        cur0, jnp.int32(0),
-    )
-    (z, _, cost, done, T, _, cur_end, _), ys = jax.lax.scan(
-        step, init,
-        (jnp.swapaxes(prices, 0, 1), jnp.swapaxes(av_i, 0, 1), sc,
-         jnp.arange(dmax)),
-    )
-    no_hist, ns_hist, cur_hist, sw_hist = ys[:4]
-    j_fin = j if p_od is None else j._replace(p_o=j.p_o * p_od[cur_end])
-    jcfg_fin = jcfg if p_od is None else _job_cfg(j_fin)
-    out = _finalize(jcfg_fin, j_fin, tput, z, cost, done, T, no_hist, ns_hist)
-    out["region"] = cur_hist
-    out["migrations"] = sw_hist.astype(jnp.int32).sum()
-    if collect:
-        keys = (_TEL_SLOTS + _TEL_REGION
-                + (_TEL_FALLBACK if fallback is not None else ()))
-        for key, hist in zip(keys, ys[4:]):
-            out[key] = hist
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("tput", "backend", "delta_mig",
-                                             "collect", "fallback"))
-def _pool_jobs_ahap_regions(omega, v, sigma, rho, rsel, rmargin,
-                            jobs: JobArrays, tput, prices, avail, pred,
-                            backend: str, delta_mig: int,
-                            collect: bool = False, fallback=None, p_od=None):
-    def per_job(job_row, pr_, av_, pm_):
-        return _simulate_lanes_ahap_regions(
-            omega, v, sigma, rho, rsel, rmargin, job_row, tput,
-            pr_, av_, pm_, backend, delta_mig,
-            collect=collect, fallback=fallback, p_od=p_od,
-        )
-
-    return jax.vmap(per_job)(jobs, prices, avail, pred)
-
-
-@functools.partial(jax.jit, static_argnames=("tput", "delta_mig", "collect",
-                                             "fallback"))
-def _pool_jobs_cheap_regions(kind, sigma, cfrac, rsel, rmargin,
-                             jobs: JobArrays, tput, prices, avail, pred,
-                             delta_mig: int, collect: bool = False,
-                             fallback=None, p_od=None):
-    def per_job(job_row, pr_, av_, pm_):
-        scores = _region_scores(job_row, pr_, av_.astype(jnp.int32), pm_)
-        fn = lambda k, s, c, rs, rm: _simulate_one_cheap_regions(
-            k, s, c, rs, rm, job_row, tput, pr_, av_, scores, delta_mig,
-            collect=collect, fallback=fallback, p_od=p_od,
-        )
-        return jax.vmap(fn)(kind, sigma, cfrac, rsel, rmargin)
-
-    return jax.vmap(per_job)(jobs, prices, avail, pred)
-
-
-def _as_p_od(p_od, n_regions: int):
-    """Normalize a per-region on-demand price multiplier: None passes
-    through (the flat-od program is traced unchanged), a scalar broadcasts
-    to (R,), an (R,) array is taken as-is."""
-    if p_od is None:
-        return None
-    x = p_od if isinstance(p_od, jax.Array) else np.asarray(p_od)
-    counters.h2d(x)
-    # a program each for the float32 conversion, the reshape and the
-    # broadcast, where they change anything
-    counters.add("launches", int(x.dtype != np.float32) + int(x.ndim != 1)
-                 + int(x.size != n_regions))
-    return jnp.broadcast_to(
-        jnp.asarray(p_od, jnp.float32).reshape(-1), (n_regions,)
-    )
-
-
-def simulate_pool_regions(pool_arrays: dict, jobs: JobArrays,
-                          tput: ThroughputConfig, prices, avail, pred,
-                          backend: str = "xla", *, delta_mig: int,
-                          collect: bool = False, fallback=None, p_od=None):
-    """Multi-region :func:`simulate_pool_jobs`: jobs x pool over an R-region
-    market. ``prices``/``avail`` are (J, R, d_max), ``pred`` is
-    (J, R, d_max, W1MAX, 2) (see ``prepare_inputs_regions``); ``delta_mig``
-    is the checkpoint-transfer cost in lost slots — required (pass
-    ``market.delta_mig``; a default here would silently override the cost a
-    RegionalMarket was built with). Lanes read their region-selection
-    strategy from pool_arrays' ``rsel``/``rmargin`` slots
-    (policy_pool.region_pool; absent keys mean every lane stays put).
-
-    Returns the ``simulate_pool_jobs`` leaves (J, P, ...) plus ``region``
-    (the lane's region each slot) and ``migrations`` (completed switches).
-    With R == 1 the shared leaves are bitwise-identical to
-    ``simulate_pool_jobs``.
-
-    ``collect=True`` adds the (J, P, T) ``tel_*`` flight-recorder series
-    plus ``tel_region``/``tel_migration`` (per-slot occupancy and switch
-    events; slot sums reconcile against ``migrations`` in
-    obs.ledger.migration_reconciliation); ``fallback`` (static
-    repro.chaos.FallbackConfig) arms the AHAP lanes' per-lane online
-    prediction-failure monitor; ``p_od`` (scalar or (R,)) scales the
-    on-demand price by occupied region (``market.p_od``; multipliers of the
-    job's flat ``on_demand_price``). All three default to the
-    bitwise-pinned shipped program."""
-    p_od = _as_p_od(p_od, np.shape(prices)[1])
-    return _run_partitioned(
-        pool_arrays,
-        lambda w, v, s, r, rs, rm: counters.launch(
-            _pool_jobs_ahap_regions, w, v, s, r, rs, rm, jobs, tput, prices,
-            avail, pred, backend, delta_mig, collect, fallback, p_od,
-        ),
-        lambda k, s, c, rs, rm: counters.launch(
-            _pool_jobs_cheap_regions, k, s, c, rs, rm, jobs, tput, prices,
-            avail, pred, delta_mig, collect, fallback, p_od,
-        ),
-        axis=1, with_regions=True,
-    )
-
-
-def simulate_pool_regions_sharded(
-    pool_arrays: dict,
-    jobs: JobArrays,
-    tput: ThroughputConfig,
-    prices, avail, pred,
-    backend: str = "xla",
-    *,
-    delta_mig: int,
-    mesh=None,
-    collect: bool = False,
-    fallback=None,
-    p_od=None,
-):
-    """Device-sharded :func:`simulate_pool_regions`: jobs (and, on a 2-D
-    pool mesh, lanes) shard exactly as in
-    :func:`simulate_pool_jobs_sharded`; the small region axis rides along
-    whole per device inside the (J, R, T) market tensors (``p_od``, when
-    set, is replicated to every device). BITWISE-equal to
-    ``simulate_pool_regions`` (pinned in tests/test_region_sim.py and the
-    forced-4-device subprocess in tests/test_sharded_pool.py); falls
-    through to it on one device. ``collect``/``fallback``/``p_od`` as in
-    :func:`simulate_pool_regions` (per-(job, lane)-cell local, so sharded
-    runs stay bitwise-equal to unsharded ones)."""
-    from repro.launch.mesh import make_pool_mesh
-
-    if mesh is None:
-        mesh = make_pool_mesh()
-    if int(np.prod(mesh.devices.shape)) == 1:
-        return simulate_pool_regions(
-            pool_arrays, jobs, tput, prices, avail, pred, backend=backend,
-            delta_mig=delta_mig, collect=collect, fallback=fallback,
+            collect=collect, fallback=fallback, delta_mig=delta_mig,
             p_od=p_od,
         )
     return _run_partitioned_sharded(
         pool_arrays, jobs, tput, prices, avail, pred, backend, mesh,
-        with_regions=True, delta_mig=int(delta_mig), collect=collect,
-        fallback=fallback, p_od=_as_p_od(p_od, np.shape(prices)[1]),
-    )
-
-
-def prepare_inputs_regions(market, pred_matrix, d_max: int):
-    """Regional twin of :func:`prepare_inputs`: (R, d_max) prices/avail and
-    an (R, d_max, W1MAX, 2) prediction stack (pad/trim per region; None
-    falls back to broadcasting the observed present, as single-region)."""
-    prices = jnp.asarray(market.prices[:, :d_max], jnp.float32)
-    avail = jnp.asarray(market.avail[:, :d_max], jnp.int32)
-    if pred_matrix is None:
-        pm = np.zeros(market.prices[:, :d_max].shape + (W1MAX, 2), np.float32)
-        pm[..., 0] = np.asarray(market.prices[:, :d_max])[..., None]
-        pm[..., 1] = np.asarray(market.avail[:, :d_max])[..., None]
-    else:
-        pm = np.asarray(pred_matrix[:, :d_max, :W1MAX], np.float32)
-        if pm.shape[2] < W1MAX:
-            pad = np.repeat(pm[:, :, -1:], W1MAX - pm.shape[2], axis=2)
-            pm = np.concatenate([pm, pad], axis=2)
-    return prices, avail, jnp.asarray(pm)
-
-
-@functools.partial(jax.jit, static_argnames=("tput", "backend"))
-def simulate_pool_monolithic(pool_arrays: dict, j: JobArrays,
-                             tput: ThroughputConfig, prices, avail, pred,
-                             backend: str = "xla-gather"):
-    """The seed path: every lane runs every rule (window DP included) and
-    selects by kind. Kept as the perf baseline (benchmarks/pool_sim_bench.py)
-    and as a parity cross-check for the partitioned path."""
-    n = len(pool_arrays["kind"])
-    rho = pool_arrays.get("rho")
-    rho = jnp.ones(n, jnp.float32) if rho is None else jnp.asarray(rho)
-    cfrac = pool_arrays.get("cfrac")
-    cfrac = jnp.zeros(n, jnp.float32) if cfrac is None else jnp.asarray(cfrac)
-    fn = lambda k, w, v, s, r, c: simulate_one(
-        k, w, v, s, j, tput, prices, avail, pred, rho=r, cfrac=c,
-        backend=backend,
-    )
-    return jax.vmap(fn)(
-        jnp.asarray(pool_arrays["kind"]), jnp.asarray(pool_arrays["omega"]),
-        jnp.asarray(pool_arrays["v"]), jnp.asarray(pool_arrays["sigma"]),
-        rho, cfrac,
+        collect=collect, fallback=fallback,
+        region=_region(prices, delta_mig, p_od),
     )
 
 
@@ -1600,16 +1291,21 @@ def unstack_jobs(jobs: JobArrays):
 
 
 def prepare_inputs(trace, pred_matrix, d_max: int):
-    """Pad/trim trace + prediction matrix to (d_max, ...) jnp arrays."""
-    prices = jnp.asarray(trace.prices[:d_max], jnp.float32)
-    avail = jnp.asarray(trace.avail[:d_max], jnp.int32)
+    """Pad/trim a market's rows and a prediction matrix to ``d_max`` slots,
+    as jnp arrays: (d_max,) prices/avail and a (d_max, W1MAX, 2) forecast
+    stack for a single-market Trace; the same with a leading region axis
+    (R, ...) for a RegionalMarket. ``pred_matrix=None`` broadcasts the
+    observed present over the forecast window."""
+    prices = np.asarray(trace.prices)[..., :d_max]
+    avail = np.asarray(trace.avail)[..., :d_max]
     if pred_matrix is None:
-        pm = np.zeros((d_max, W1MAX, 2), np.float32)
-        pm[:, :, 0] = np.asarray(trace.prices[:d_max])[:, None]
-        pm[:, :, 1] = np.asarray(trace.avail[:d_max])[:, None]
+        pm = np.zeros(prices.shape + (W1MAX, 2), np.float32)
+        pm[..., 0] = prices[..., None]
+        pm[..., 1] = avail[..., None]
     else:
-        pm = np.asarray(pred_matrix[:d_max, :W1MAX], np.float32)
-        if pm.shape[1] < W1MAX:
-            pad = np.repeat(pm[:, -1:], W1MAX - pm.shape[1], axis=1)
-            pm = np.concatenate([pm, pad], axis=1)
-    return prices, avail, jnp.asarray(pm)
+        pm = np.asarray(pred_matrix[..., :d_max, :W1MAX, :], np.float32)
+        if pm.shape[-2] < W1MAX:
+            pad = np.repeat(pm[..., -1:, :], W1MAX - pm.shape[-2], axis=-2)
+            pm = np.concatenate([pm, pad], axis=-2)
+    return (jnp.asarray(prices, jnp.float32), jnp.asarray(avail, jnp.int32),
+            jnp.asarray(pm))

@@ -9,8 +9,9 @@ the numpy parity oracle ``core.multi_job.MultiJobScheduler``:
   * **demand phase** — every live job's policy decides against the FULL
     slot supply. The AHAP rule (``fast_sim._ahap_rule_batch``, the
     slot-major batched window DP over per-job local clocks ``t -
-    arrival``) and the five cheap kinds' vectorized rules all run over
-    every row, and each row keeps the demand of its own kind;
+    arrival``) and the five cheap kinds' vectorized rules
+    (``fast_sim._cheap_rules``) all run over every row, and each row keeps
+    the demand of its own kind;
   * **waterfall phase** — spot demand is granted least-slack-first as a
     sort + cumulative-supply clip instead of a Python loop: with demands
     sorted by the float32 slack key (job-id tie-break), ``grant_i =
@@ -198,8 +199,9 @@ def _fleet_scan(pol, jobs, arrivals, tput, prices, avail, pred,
             pr_t, thr_t, zee_t, eff_t,
         )
         pa = jnp.where(lt >= 1, sup_p, sup)
-        an_o, an_s = fast_sim._ahanp_rule(
-            jobs, pol["sigma"], z, lt, price, sup, n_prev, pa)
+        conds, cand_o, cand_s = fast_sim._cheap_rules(
+            kind, jobs, tput, pol["sigma"], pol["cfrac"], z, lt, price, sup,
+            n_prev, pa)
         if fb_on:
             # the monitor only accumulates once the job is watching the
             # market (arrived); the shared error sample is scalar
@@ -209,17 +211,12 @@ def _fleet_scan(pol, jobs, arrivals, tput, prices, avail, pred,
                 err,
             )
             fb = is_ahap & (err > fb_thr)
-            ah_o = jnp.where(fb, an_o, ah_o)
-            ah_s = jnp.where(fb, an_s, ah_s)
-        od_o, od_s = fast_sim._od_rule(jobs, tput, z, lt, price, sup)
-        ms_o, ms_s = fast_sim._msu_rule(jobs, tput, z, lt, price, sup)
-        up_o, up_s = fast_sim._up_rule(jobs, tput, z, lt, price, sup)
-        rd_o, rd_s = fast_sim._rand_rule(
-            jobs, tput, pol["cfrac"], z, lt, price, sup)
-        sel = [is_ahap, kind == 1, kind == 2, kind == 3, kind == 4,
-               kind == 5]
-        d_o = jnp.select(sel, [ah_o, an_o, od_o, ms_o, up_o, rd_o])
-        d_s = jnp.select(sel, [ah_s, an_s, od_s, ms_s, up_s, rd_s])
+            # AHANP is the first of the cheap candidates
+            ah_o = jnp.where(fb, cand_o[0], ah_o)
+            ah_s = jnp.where(fb, cand_s[0], ah_s)
+        sel = [is_ahap, *conds()]
+        d_o = jnp.select(sel, [ah_o, *cand_o])
+        d_s = jnp.select(sel, [ah_s, *cand_s])
         # demand clip against the full pool; dead jobs demand nothing
         d_s = jnp.clip(d_s, 0, jnp.minimum(sup, jobs.n_max))
         d_o = jnp.clip(d_o, 0, jobs.n_max - d_s)

@@ -360,7 +360,7 @@ RSEL_BIG = np.float32(1e6)
 # the fast lanes score identically regardless of the predictor's horizon:
 # shorter forecasts are edge-padded, longer ones trimmed. Must equal
 # fast_sim.W1MAX (asserted there), which pads its prediction inputs the
-# same way (prepare_inputs_regions).
+# same way (prepare_inputs).
 RSEL_PRED_WINDOW = 6
 
 
@@ -372,7 +372,7 @@ class RegionSelectorParams:
 
 class RegionSelector:
     """Reference per-slot region chooser — the python twin of the vectorized
-    score + hysteresis step inside fast_sim.simulate_pool_regions.
+    score + hysteresis step inside fast_sim's region-aware lane scans.
 
     Scores are LOWER-better, computed in float32 so the f32 fast-sim lanes
     and this reference make identical switch decisions:

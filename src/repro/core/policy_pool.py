@@ -21,8 +21,9 @@ Thm. 2's regret only grows as sqrt(log M)):
 * Region lanes (``region_pool``): scheduling policies crossed with
   multi-region selection strategies (greedy-price / greedy-avail /
   predicted-horizon, plain and hysteresis-sticky) for
-  fast_sim.simulate_pool_regions — the selector learns region strategy and
-  scheduling policy jointly (SkyNomad, arXiv:2601.06520).
+  fast_sim.simulate_pool_jobs on a regional market — the selector learns
+  region strategy and scheduling policy jointly (SkyNomad,
+  arXiv:2601.06520).
 """
 from __future__ import annotations
 

@@ -18,11 +18,11 @@ This module provides:
   simulate_regional    the python reference simulator: region selection
                        (policies.RegionSelector) layered over the paper's
                        slot execution — the oracle the vectorized
-                       fast_sim.simulate_pool_regions lanes are pinned to
+                       fast_sim.simulate_pool_jobs region lanes are pinned to
 
-The JAX hot path lives in fast_sim.simulate_pool_regions; the pool lanes
-that pair a scheduling policy with a region strategy come from
-policy_pool.region_pool.
+The JAX hot path is fast_sim.simulate_pool_jobs on (J, R, d) market rows;
+the pool lanes that pair a scheduling policy with a region strategy come
+from policy_pool.region_pool.
 """
 from __future__ import annotations
 
@@ -208,7 +208,7 @@ def simulate_regional(
     agreement with the fast AHAP lanes, ``pred_matrix`` must cover the
     policy's window — pass a predictor horizon >= the largest omega (i.e.
     fast_sim.W1MAX - 1), or the edge-padded matrix from
-    ``prepare_inputs_regions``. Region *scores* are horizon-robust either
+    ``fast_sim.prepare_inputs``. Region *scores* are horizon-robust either
     way (RegionSelector pads to RSEL_PRED_WINDOW itself); a too-short
     forecast only starves the python AHAP's plan window relative to the
     padded one the fast lanes see.

@@ -182,8 +182,8 @@ def run_eg_scan(state: EGState, utilities, track_history: bool = False,
     utility chunks, which is what
     core.engine's job-chunked streaming mode relies on — for both the
     single-region and the regional engine path: the scan is agnostic to
-    where the (K, M) utilities came from (``simulate_pool_jobs`` or
-    ``simulate_pool_regions``), which is why R == 1 engine runs are
+    where the (K, M) utilities came from (``simulate_pool_jobs`` on a flat
+    or a regional market), which is why R == 1 engine runs are
     bitwise-identical end to end."""
     u_all = jnp.clip(jnp.asarray(utilities, jnp.float32), 0.0, 1.0)
     tiny = jnp.float32(np.finfo(np.float32).tiny)

@@ -45,7 +45,7 @@ cheap lanes, which carry no monitor, report all-zero rows):
 ``pred_err``        f32, that realized-forecast-error EWMA after the slot
 ==================  ========================================================
 
-Region runs (``simulate_pool_regions[_sharded]`` with ``collect=True``)
+Region runs (region-axis ``simulate_pool_jobs[_sharded]``, ``collect=True``)
 add the migration series (``None`` for single-region runs):
 
 ==============  ============================================================

@@ -128,8 +128,8 @@ def fallback_events(active) -> dict:
 
 def migration_reconciliation(out: dict) -> dict:
     """Reconcile the per-slot migration series against the region engine's
-    summary leaves (a ``simulate_pool_regions[_sharded]`` ``collect=True``
-    run).
+    summary leaves (a region-axis ``simulate_pool_jobs[_sharded]``
+    ``collect=True`` run).
 
     Two invariants are checked, not trusted:
 
@@ -180,8 +180,8 @@ def pool_ledger(out: dict, jobs, tput, lane_names: Optional[Sequence[str]] =
 
     ``out`` leaves are (J, P[, T]); per-lane aggregations reduce over the
     jobs axis. ``lane_names`` (length P) labels the per-lane block. Region
-    runs (``simulate_pool_regions[_sharded]``) get a ``migration`` block —
-    :func:`migration_reconciliation` over their ``tel_region`` /
+    runs (region-axis ``simulate_pool_jobs[_sharded]``) get a ``migration``
+    block — :func:`migration_reconciliation` over their ``tel_region`` /
     ``tel_migration`` series."""
     fr = _frame.frame_from_out(out)
     util = np.asarray(out["utility"], np.float64)     # (J, P)

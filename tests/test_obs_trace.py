@@ -173,8 +173,10 @@ def test_capture_changes_nothing(captured):
 
 
 def test_sharded_runners_named_apart():
-    """Each sharded partition runner, and the sharded fleet's, carries a
-    name of its own, which a device trace shows as ``jit_<name>``."""
+    """Each kind's sharded partition runner — on a flat or a regional
+    market, with or without per-region on-demand prices — and the sharded
+    fleet's carry a name of their own, which a device trace shows as
+    ``jit_<name>``."""
     from jax.sharding import PartitionSpec
 
     from repro.core import fleet
@@ -183,15 +185,12 @@ def test_sharded_runners_named_apart():
     mesh = make_pool_mesh(jax.devices()[:1])
     spec = PartitionSpec()
     names = {
-        fast_sim._sharded_pool_call(mesh, PAPER_TPUT, "xla", 1, regions,
+        fast_sim._sharded_pool_call(mesh, PAPER_TPUT, "xla", delta_mig,
                                     ahap, spec, spec, spec,
                                     has_p_od=pod).__name__
-        for ahap in (True, False) for regions, pod in (
-            (False, False), (True, False), (True, True))}
-    assert names == {"sharded_pool_ahap", "sharded_pool_cheap",
-                     "sharded_pool_ahap_regions", "sharded_pool_cheap_regions",
-                     "sharded_pool_ahap_regions_pod",
-                     "sharded_pool_cheap_regions_pod"}
+        for ahap in (True, False) for delta_mig, pod in (
+            (None, False), (1, False), (1, True))}
+    assert names == {"sharded_pool_ahap", "sharded_pool_cheap"}
     assert fleet._sharded_fleet_call(
         mesh, PAPER_TPUT, "xla").__name__ == "sharded_fleet_scan"
 

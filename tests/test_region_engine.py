@@ -1,4 +1,4 @@
-"""Regional selection engine (core.engine's ``delta_mig`` mode + the
+"""Regional selection engine (core.engine on region-axis inputs + the
 streamed device-side prediction prep).
 
 Pins, per the engine's contracts:

@@ -1,6 +1,8 @@
 """Region-aware pool simulation: R=1 bitwise parity, reference parity,
-migration-cost accounting, and the hysteresis no-thrash property."""
+migration-cost accounting, the hysteresis no-thrash property, and the
+entry points' input rules."""
 import numpy as np
+import pytest
 
 from repro.configs.base import JobConfig, ThroughputConfig
 from repro.core import fast_sim
@@ -52,7 +54,7 @@ def test_r1_bitwise_parity_with_simulate_pool_jobs():
             fast_sim.W1MAX - 1
         )
         prices, avail, pm = fast_sim.prepare_inputs(tr, pred, JOB.deadline)
-        rp, ra, rpm = fast_sim.prepare_inputs_regions(
+        rp, ra, rpm = fast_sim.prepare_inputs(
             RegionalMarket.from_traces([tr]), pred[None], JOB.deadline
         )
         prices_l.append(prices); avail_l.append(avail); pm_l.append(pm)
@@ -61,7 +63,7 @@ def test_r1_bitwise_parity_with_simulate_pool_jobs():
         arrs, stacked, TPUT, np.stack(prices_l), np.stack(avail_l),
         np.stack(pm_l),
     )
-    regional = fast_sim.simulate_pool_regions(
+    regional = fast_sim.simulate_pool_jobs(
         arrs, stacked, TPUT, np.stack(rp_l), np.stack(ra_l), np.stack(rpm_l),
         delta_mig=1,
     )
@@ -73,12 +75,36 @@ def test_r1_bitwise_parity_with_simulate_pool_jobs():
     assert np.all(np.asarray(regional["region"]) == 0)
 
 
+@pytest.mark.parametrize("entry", ["simulate_pool_jobs",
+                                   "simulate_pool_jobs_sharded"])
+@pytest.mark.parametrize("regional, kw", [
+    (True, {}),
+    (False, {"delta_mig": 1}),
+    (False, {"p_od": 1.5}),
+], ids=["region_axis_without_delta_mig", "delta_mig_on_flat_rows",
+        "p_od_on_flat_rows"])
+def test_entry_points_refuse_mismatched_region_options(entry, regional, kw):
+    """The market's rank decides the region axis, and the options that
+    belong to it follow: (J, R, d) rows without ``delta_mig`` are refused
+    (no default may override a market's migration cost), and
+    ``delta_mig`` or ``p_od`` with flat (J, d) rows is refused too."""
+    arrs = specs_to_arrays(baseline_specs())
+    tr = constant_trace(price=0.3, avail=6, length=JOB.deadline + 1)
+    market = RegionalMarket.from_traces([tr, tr]) if regional else tr
+    prices, avail, pm = fast_sim.prepare_inputs(market, None, JOB.deadline)
+    with pytest.raises(ValueError, match="delta_mig"):
+        getattr(fast_sim, entry)(
+            arrs, fast_sim.stack_jobs([JOB]), TPUT, prices[None],
+            avail[None], pm[None], **kw,
+        )
+
+
 def test_regions_sharded_single_device_fallback_bitwise():
-    """simulate_pool_regions_sharded must fall through to (and bitwise-match)
-    simulate_pool_regions on one visible device, for the default mesh and
-    explicit 1-device meshes of either rank. The real multi-device parity
-    (jobs / lanes / 2-D layouts under 4 forced host devices) runs in the
-    tests/test_sharded_pool.py subprocess."""
+    """simulate_pool_jobs_sharded on regional inputs must fall through to
+    (and bitwise-match) simulate_pool_jobs on one visible device, for the
+    default mesh and explicit 1-device meshes of either rank. The real
+    multi-device parity (jobs / lanes / 2-D layouts under 4 forced host
+    devices) runs in the tests/test_sharded_pool.py subprocess."""
     import jax
 
     from repro.launch.mesh import make_pool_mesh
@@ -89,14 +115,14 @@ def test_regions_sharded_single_device_fallback_bitwise():
         mkt, lambda t, r: NoisyPredictor(t, "fixed_uniform", 0.2, seed=r)
     ).matrix(fast_sim.W1MAX - 1)
     arrs = specs_to_arrays(region_pool())
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, rpred, JOB.deadline)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, rpred, JOB.deadline)
     stacked = fast_sim.stack_jobs([JOB])
     tile = lambda x: np.asarray(x)[None]
-    base = fast_sim.simulate_pool_regions(
+    base = fast_sim.simulate_pool_jobs(
         arrs, stacked, TPUT, tile(rp), tile(ra), tile(rpm), delta_mig=1
     )
     for mesh in (None, make_pool_mesh(), make_pool_mesh(shape=(1, 1))):
-        sh = fast_sim.simulate_pool_regions_sharded(
+        sh = fast_sim.simulate_pool_jobs_sharded(
             arrs, stacked, TPUT, tile(rp), tile(ra), tile(rpm),
             delta_mig=1, mesh=mesh,
         )
@@ -117,8 +143,8 @@ def test_region_lanes_match_python_reference():
     ).matrix(fast_sim.W1MAX - 1)
     pool = region_pool()
     arrs = specs_to_arrays(pool)
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, rpred, JOB.deadline)
-    out = fast_sim.simulate_pool_regions(
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, rpred, JOB.deadline)
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([JOB]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=mkt.delta_mig,
@@ -158,8 +184,8 @@ def test_migration_cost_accounting_two_region_toy():
     )
     spec = PolicySpec(KIND_MSU, rsel=RSEL_PRICE, rmargin=0.0)
     arrs = specs_to_arrays([spec])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, job.deadline)
-    out = fast_sim.simulate_pool_regions(
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, job.deadline)
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), tput,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=1,
@@ -207,9 +233,9 @@ def test_per_region_od_price_accounting_two_region_toy():
     spec = PolicySpec(KIND_MSU, rsel=RSEL_PRICE, rmargin=0.0)
     arrs = specs_to_arrays([spec])
     stacked = fast_sim.stack_jobs([job])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, job.deadline)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, job.deadline)
     tile = lambda x: np.asarray(x)[None]
-    run = lambda po: fast_sim.simulate_pool_regions(
+    run = lambda po: fast_sim.simulate_pool_jobs(
         arrs, stacked, tput, tile(rp), tile(ra), tile(rpm),
         delta_mig=1, p_od=po,
     )
@@ -245,7 +271,7 @@ def test_per_region_od_price_accounting_two_region_toy():
     assert abs(ref.cost - (run_cost + term)) < 1e-3
     assert abs(ref.cost - float(np.asarray(out["cost"])[0, 0])) < 1e-3
     # sharded entry forwards p_od (single-device fallthrough, bitwise)
-    sh = fast_sim.simulate_pool_regions_sharded(
+    sh = fast_sim.simulate_pool_jobs_sharded(
         arrs, stacked, tput, tile(rp), tile(ra), tile(rpm),
         delta_mig=1, p_od=mkt.p_od,
     )
@@ -273,9 +299,9 @@ def test_hysteresis_prevents_thrash():
         PolicySpec(KIND_MSU, rsel=RSEL_PRICE, rmargin=0.10),   # sticky
     ]
     arrs = specs_to_arrays(specs)
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, d)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, d)
     job = JobConfig(workload=200.0, deadline=d, n_min=1, n_max=8, value=120.0)
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=2,
@@ -300,8 +326,8 @@ def test_free_migration_when_delta_zero():
     job = JobConfig(workload=200.0, deadline=d, n_min=1, n_max=4, value=120.0)
     tput = ThroughputConfig(alpha=1.0, beta=0.0, mu1=0.9, mu2=0.95)
     arrs = specs_to_arrays([PolicySpec(KIND_MSU, rsel=RSEL_PRICE)])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, d)
-    out = fast_sim.simulate_pool_regions(
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, d)
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), tput,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=0,
@@ -330,8 +356,8 @@ def test_no_migration_after_completion():
     job = JobConfig(workload=10.0, deadline=d, n_min=1, n_max=8, value=120.0)
     spec = PolicySpec(KIND_MSU, rsel=RSEL_PRICE)
     arrs = specs_to_arrays([spec])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, d)
-    out = fast_sim.simulate_pool_regions(
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, d)
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=1,
@@ -364,9 +390,9 @@ def test_no_migration_after_deadline_heterogeneous_batch():
     ]
     spec = PolicySpec(KIND_MSU, rsel=RSEL_PRICE)
     arrs = specs_to_arrays([spec])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, dmax)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, dmax)
     tile = lambda x: np.repeat(np.asarray(x)[None], 2, axis=0)
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs(jobs), TPUT,
         tile(rp), tile(ra), tile(rpm), delta_mig=1,
     )
@@ -381,7 +407,7 @@ def test_no_migration_after_deadline_heterogeneous_batch():
 
 def test_short_horizon_pred_scores_match_reference():
     """pred_horizon with a predictor horizon SHORTER than the scoring window:
-    prepare_inputs_regions edge-pads the forecast and the reference selector
+    prepare_inputs edge-pads the forecast and the reference selector
     pads identically (RSEL_PRED_WINDOW), so both sides pick the same regions.
     Region 0 dangles a 2-slot teaser rate that a short forecast would
     overweight without the shared padding convention."""
@@ -395,9 +421,9 @@ def test_short_horizon_pred_scores_match_reference():
     pred = RegionalPredictor(mkt).matrix(h)
     spec = PolicySpec(KIND_MSU, rsel=RSEL_PRED)
     arrs = specs_to_arrays([spec])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, pred, d)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, pred, d)
     job = JobConfig(workload=500.0, deadline=d, n_min=1, n_max=8, value=120.0)
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=1,
@@ -423,9 +449,9 @@ def test_greedy_avail_follows_capacity():
         [from_arrays(pr, av0), from_arrays(pr, av1)], delta_mig=0
     )
     arrs = specs_to_arrays([PolicySpec(KIND_MSU, rsel=RSEL_AVAIL)])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, None, d)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, None, d)
     job = JobConfig(workload=500.0, deadline=d, n_min=1, n_max=8, value=120.0)
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=0,
@@ -452,9 +478,9 @@ def test_pred_horizon_lane_uses_forecasts():
         PolicySpec(KIND_MSU, rsel=RSEL_PRICE),
         PolicySpec(KIND_MSU, rsel=RSEL_PRED),
     ])
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, pred, d)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, pred, d)
     job = JobConfig(workload=500.0, deadline=d, n_min=1, n_max=8, value=120.0)
-    out = fast_sim.simulate_pool_regions(
+    out = fast_sim.simulate_pool_jobs(
         arrs, fast_sim.stack_jobs([job]), TPUT,
         np.asarray(rp)[None], np.asarray(ra)[None], np.asarray(rpm)[None],
         delta_mig=1,

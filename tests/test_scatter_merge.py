@@ -79,27 +79,26 @@ def _region_inputs():
     rpred = RegionalPredictor(
         mkt, lambda t, r: NoisyPredictor(t, "fixed_uniform", 0.2, seed=r)
     ).matrix(fast_sim.W1MAX - 1)
-    rp, ra, rpm = fast_sim.prepare_inputs_regions(mkt, rpred,
-                                                  JOBS[0].deadline)
+    rp, ra, rpm = fast_sim.prepare_inputs(mkt, rpred, JOBS[0].deadline)
     return [np.stack([np.asarray(x)] * len(JOBS)) for x in (rp, ra, rpm)]
 
 
 def _call(entry, pool, collect=False):
-    """A closure running ``entry`` on ``pool``, and the lane axis of its
-    result leaves."""
+    """A closure running ``entry`` on ``pool``, and the lane axis the merge
+    runs on (one job's call merges its one-row batch)."""
     arrs = specs_to_arrays(POOLS[pool]())
     jobs = fast_sim.stack_jobs(JOBS)
     if entry == "pool":
         prices, avail, pred = (x[0] for x in _single_region_inputs())
         j = fast_sim.JobArrays.of(JOBS[0])
         return (lambda: fast_sim.simulate_pool(
-            arrs, j, TPUT, prices, avail, pred, collect=collect)), 0
+            arrs, j, TPUT, prices, avail, pred, collect=collect)), 1
     if entry == "jobs":
         prices, avail, pred = _single_region_inputs()
         return (lambda: fast_sim.simulate_pool_jobs(
             arrs, jobs, TPUT, prices, avail, pred, collect=collect)), 1
     prices, avail, pred = _region_inputs()
-    return (lambda: fast_sim.simulate_pool_regions(
+    return (lambda: fast_sim.simulate_pool_jobs(
         arrs, jobs, TPUT, prices, avail, pred, delta_mig=1,
         collect=collect)), 1
 
@@ -142,7 +141,15 @@ def test_merge_is_the_eager_merge_bitwise(recorded, entry, pool, collect):
     call, axis = _call(entry, pool, collect)
     result = call()
     ((parts, idxs, got_axis, out, _),) = recorded
-    assert got_axis == axis and out is result
+    assert got_axis == axis
+    if entry == "pool":
+        # one job's result is the merged one-row batch's row
+        assert list(result) == list(out)
+        for k in out:
+            np.testing.assert_array_equal(np.asarray(result[k]),
+                                          np.asarray(out[k][0]), err_msg=k)
+    else:
+        assert out is result
     kind = np.asarray(specs_to_arrays(POOLS[pool]())["kind"])
     assert len(parts) == int((kind == KIND_AHAP).any()) + int(
         (kind != KIND_AHAP).any())

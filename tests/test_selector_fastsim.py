@@ -234,24 +234,31 @@ def test_fast_sim_batched_lanes_match_vmap_oracle():
 
 
 def test_fast_sim_partitioned_matches_monolithic():
-    """The kind-partitioned pool path is bitwise-pinned to the seed
-    monolithic path (same lanes, same order, same leaves) — RAND_DEADLINE
-    lanes included."""
+    """The kind-partitioned pool path on a mixed pool — AHAP, AHANP,
+    RAND_DEADLINE and the baselines, each kind's lanes scattered back to
+    pool order — matches the reference simulator lane by lane: the same
+    allocation every slot and the same utility."""
     pool = (paper_pool(omegas=(2, 4), sigmas=(0.4, 0.8))
             + rand_deadline_pool((0.2, 0.6)) + baseline_specs())
+    assert len({s.kind for s in pool}) == 6
     arrs = specs_to_arrays(pool)
     tr = vast_like_trace(seed=5, days=1).window(0, 10)
     pred = NoisyPredictor(tr, "fixed_uniform", 0.2, seed=5).matrix(
         fast_sim.W1MAX - 1
     )
     prices, avail, pm = fast_sim.prepare_inputs(tr, pred, JOB.deadline)
-    j = fast_sim.JobArrays.of(JOB)
-    mono = fast_sim.simulate_pool_monolithic(arrs, j, TPUT, prices, avail, pm)
-    part = fast_sim.simulate_pool(arrs, j, TPUT, prices, avail, pm)
-    for k in mono:
+    part = fast_sim.simulate_pool(
+        arrs, fast_sim.JobArrays.of(JOB), TPUT, prices, avail, pm
+    )
+    assert np.shape(part["utility"]) == (len(pool),)
+    for i, spec in enumerate(pool):
+        r = simulate(spec.build(), JOB, TPUT, tr,
+                     pred if spec.kind == 0 else None)
+        assert abs(r.utility - float(part["utility"][i])) < 1e-2, spec.name
         np.testing.assert_array_equal(
-            np.asarray(mono[k]), np.asarray(part[k]), err_msg=k
-        )
+            np.asarray(part["n_od"][i]), r.n_od, err_msg=spec.name)
+        np.testing.assert_array_equal(
+            np.asarray(part["n_spot"][i]), r.n_spot, err_msg=spec.name)
 
 
 def test_pool_sizes_match_paper():

@@ -1,7 +1,7 @@
 """Sharded-vs-single-device parity for the policy-pool simulator.
 
-``simulate_pool_jobs_sharded`` / ``simulate_pool_regions_sharded`` must be
-BITWISE-equal to their unsharded twins — per-(job, lane) cells are
+``simulate_pool_jobs_sharded`` on flat and on region-axis markets must be
+BITWISE-equal to ``simulate_pool_jobs`` — per-(job, lane) cells are
 independent and every op is elementwise over both grid axes, so laying the
 grid over a device mesh (jobs-only 1-D, lanes-only, or the 2-D
 (jobs, lanes) mesh) may not change a single bit. The multi-device half runs
@@ -136,10 +136,10 @@ rpm = np.stack([
     for w in wins
 ]).astype(np.float32)
 stacked = fast_sim.stack_jobs(jobs)
-rbase = fast_sim.simulate_pool_regions(
+rbase = fast_sim.simulate_pool_jobs(
     rarrs, stacked, TPUT, rp, ra, rpm, delta_mig=1)
 for shape in MESHES:
-    sh = fast_sim.simulate_pool_regions_sharded(
+    sh = fast_sim.simulate_pool_jobs_sharded(
         rarrs, stacked, TPUT, rp, ra, rpm, delta_mig=1,
         mesh=None if shape is None else make_pool_mesh(shape=shape),
     )
@@ -154,11 +154,11 @@ for shape in MESHES:
 # multipliers — all three shard bitwise on every mesh layout, and the
 # collect run's shared keys match the plain region run
 p_od = np.array([1.0, 1.5, 0.7], np.float32)
-rfull = fast_sim.simulate_pool_regions(
+rfull = fast_sim.simulate_pool_jobs(
     rarrs, stacked, TPUT, rp, ra, rpm, delta_mig=1,
     collect=True, fallback=FallbackConfig(threshold=0.5, lam=0.5),
     p_od=p_od)
-rtel = fast_sim.simulate_pool_regions(
+rtel = fast_sim.simulate_pool_jobs(
     rarrs, stacked, TPUT, rp, ra, rpm, delta_mig=1, collect=True)
 assert len(rtel) == len(rbase) + 9, sorted(rtel)
 assert len(rfull) == len(rbase) + 11, sorted(rfull)
@@ -176,7 +176,7 @@ for name, ref, kw in (
                          p_od=p_od)),
 ):
     for shape in MESHES:
-        sh = fast_sim.simulate_pool_regions_sharded(
+        sh = fast_sim.simulate_pool_jobs_sharded(
             rarrs, stacked, TPUT, rp, ra, rpm, delta_mig=1,
             mesh=None if shape is None else make_pool_mesh(shape=shape),
             **kw)
