@@ -188,13 +188,23 @@ def _solve_xla_batch(slot_cost, gain, tn: int):
     obj = jnp.where(C < _BIG / 2, obj, -jnp.inf)
     u_star = jnp.argmax(obj, axis=1)  # (B,) smallest-u on ties, like argmax
 
+    # Each row's k at u is read by a masked sum over the unit axis, not a
+    # per-row gather: one int32 term is left unmasked, so the sum is that
+    # value, and the read is dense (a gather of single elements cost ~8 ns
+    # each on a TPU v5e).
+    # u stays in [0, U]: k = 0 is free in every slot, so u_star is reachable,
+    # and a reachable state only steps to reachable states.
+    units = jnp.arange(U + 1)
+
     def back_step(u, choice_row):
-        k = jnp.take_along_axis(choice_row, u[:, None], axis=1)[:, 0]
+        k = jnp.sum(jnp.where(units == u[:, None], choice_row, 0), axis=1,
+                    dtype=choice_row.dtype)
         return u - k, k
 
     _, k_rev = jax.lax.scan(back_step, u_star, choices, reverse=True)
     n_tot = jnp.swapaxes(k_rev, 0, 1).astype(jnp.int32)  # (B, w1)
-    return n_tot, jnp.take_along_axis(obj, u_star[:, None], axis=1)[:, 0]
+    # obj[u_star] is the row's max: argmax picks a first maximum (-inf rows too)
+    return n_tot, jnp.max(obj, axis=1)
 
 
 @jax.named_scope("window_dp")

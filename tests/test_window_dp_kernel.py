@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from _hyp_compat import given, settings, st
 from repro.configs.base import JobConfig, ThroughputConfig
 from repro.core.window_opt import (
+    _dp_step_shifted_batch,
     _solve_xla_batch,
     _unit_cost_table,
     brute_force_window,
@@ -142,22 +143,77 @@ def test_solve_window_batch_matches_vmap(seed, w1, b, job):
         )
 
 
-def test_solve_xla_batch_matches_oracle():
-    """The lane-batched shifted-slice DP against the pure-jnp scan oracle on
-    raw batched tables (same randomized pricing-out as the kernel test)."""
-    for b, w1, tn in [(1, 6, 16), (7, 4, 8), (24, 2, 5)]:
-        rng = np.random.default_rng(b * 17 + w1)
-        kw, u1 = tn + 1, w1 * tn + 1
-        slot_cost = rng.uniform(0.0, 3.0, (b, w1, kw)).astype(np.float32)
-        slot_cost = np.where(rng.random((b, w1, kw)) < 0.3, 1.0e9, slot_cost)
-        slot_cost[:, :, 0] = 0.0
-        gain = np.cumsum(rng.uniform(0.0, 2.0, (b, u1)), axis=1).astype(np.float32)
-        n_tot, obj = _solve_xla_batch(
-            jnp.asarray(slot_cost), jnp.asarray(gain), tn
-        )
-        n_ref, o_ref = window_dp_ref(jnp.asarray(slot_cost), jnp.asarray(gain))
-        np.testing.assert_array_equal(np.asarray(n_tot), np.asarray(n_ref))
-        np.testing.assert_allclose(np.asarray(obj), np.asarray(o_ref), rtol=1e-6)
+def _solve_xla_batch_gather_backtrack(slot_cost, gain, tn):
+    """The batched solver as it was with a per-lane gather in its backtrack
+    (``take_along_axis`` on the choices and on the objective): the bitwise
+    reference for the masked-reduction backtrack."""
+    b, w1, _ = slot_cost.shape
+    U = w1 * tn
+    C0 = jnp.broadcast_to(
+        jnp.where(jnp.arange(U + 1) == 0, 0.0, 1.0e9), (b, U + 1)
+    )
+    C, choices = jax.lax.scan(
+        lambda C, row: _dp_step_shifted_batch(C, row, tn, U),
+        C0, jnp.swapaxes(slot_cost, 0, 1),
+    )
+    obj = jnp.where(C < 1.0e9 / 2, gain - C, -jnp.inf)
+    u_star = jnp.argmax(obj, axis=1)
+
+    def back_step(u, choice_row):
+        k = jnp.take_along_axis(choice_row, u[:, None], axis=1)[:, 0]
+        return u - k, k
+
+    _, k_rev = jax.lax.scan(back_step, u_star, choices, reverse=True)
+    n_tot = jnp.swapaxes(k_rev, 0, 1).astype(jnp.int32)
+    return n_tot, jnp.take_along_axis(obj, u_star[:, None], axis=1)[:, 0]
+
+
+# the cells' shapes: 105 AHAP lanes a job, 1000 fleet rows; w1 = 6, tn = 16
+@pytest.mark.parametrize(
+    "b,w1,tn", [(1, 6, 16), (7, 4, 8), (24, 2, 5), (105, 6, 16), (1000, 6, 16)]
+)
+def test_solve_xla_batch_matches_oracle(b, w1, tn):
+    """The lane-batched shifted-slice DP against the pure-jnp scan oracle and
+    the gather-backtrack reference on raw batched tables (same randomized
+    pricing-out as the kernel test). From two rows up the last two are edge
+    rows: every k > 0 priced out, and exact ties in the objective (and in
+    the DP's k)."""
+    rng = np.random.default_rng(b * 17 + w1)
+    kw, u1 = tn + 1, w1 * tn + 1
+    slot_cost = rng.uniform(0.0, 3.0, (b, w1, kw)).astype(np.float32)
+    slot_cost = np.where(rng.random((b, w1, kw)) < 0.3, 1.0e9, slot_cost)
+    slot_cost[:, :, 0] = 0.0
+    gain = np.cumsum(rng.uniform(0.0, 2.0, (b, u1)), axis=1).astype(np.float32)
+    if b >= 2:
+        slot_cost[-2, :, 1:] = 1.0e9
+        # cost 0.5 a unit in every slot, gain 0.5 a unit plus 2 from u = 4:
+        # the objective is exactly 2.0 for every u >= 4
+        slot_cost[-1] = 0.5 * np.arange(kw, dtype=np.float32)
+        units = np.arange(u1, dtype=np.float32)
+        gain[-1] = 0.5 * units + np.where(units >= 4, 2.0, 0.0)
+    args = (jnp.asarray(slot_cost), jnp.asarray(gain))
+    n_tot, obj = _solve_xla_batch(*args, tn)
+    n_old, o_old = _solve_xla_batch_gather_backtrack(*args, tn)
+    n_ref, o_ref = window_dp_ref(*args)
+    for n_want, o_want in ((n_old, o_old), (n_ref, o_ref)):
+        np.testing.assert_array_equal(np.asarray(n_tot), np.asarray(n_want))
+        np.testing.assert_array_equal(np.asarray(obj), np.asarray(o_want))
+    if b >= 2:
+        assert np.all(np.asarray(n_tot)[-2] == 0)
+        assert np.asarray(n_tot)[-1].sum() == 4 and float(obj[-1]) == 2.0
+
+
+def test_solve_xla_batch_lowers_without_gather():
+    """The batched backtrack reads each row's choice by a dense masked
+    reduction: at the cells' shape (B, w1, tn+1) = (1000, 6, 17) the lowered
+    solver holds no gather, so a scattered per-lane read cannot come back
+    unseen."""
+    text = jax.jit(_solve_xla_batch, static_argnums=2).lower(
+        jax.ShapeDtypeStruct((1000, 6, 17), jnp.float32),
+        jax.ShapeDtypeStruct((1000, 6 * 16 + 1), jnp.float32),
+        16,
+    ).as_text()
+    assert "gather" not in text
 
 
 @settings(max_examples=6, deadline=None)
